@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import resource
 import subprocess
 import sys
@@ -127,7 +128,8 @@ def _spawn(kw: dict) -> dict:
     proc = subprocess.run(
         [sys.executable, str(Path(__file__).resolve()), "--run-one",
          json.dumps(kw)],
-        capture_output=True, text=True)
+        capture_output=True, text=True,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})   # never the accelerator
     if proc.returncode != 0:
         raise RuntimeError(
             f"bench case {kw} failed:\n{proc.stdout}\n{proc.stderr}")

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import resource
 import subprocess
 import sys
@@ -124,7 +125,8 @@ def _spawn(kw: dict) -> dict:
     proc = subprocess.run(
         [sys.executable, str(Path(__file__).resolve()), "--run-one",
          json.dumps(kw)],
-        capture_output=True, text=True)
+        capture_output=True, text=True,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})   # never the accelerator
     if proc.returncode != 0:
         raise RuntimeError(
             f"scale case {kw} failed:\n{proc.stdout}\n{proc.stderr}")

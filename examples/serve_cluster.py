@@ -18,7 +18,8 @@ cost-model estimate (--clock analytic, the cross-backend parity mode).
 
 Scenario traces carry cluster-scale token counts; the backend maps them to
 engine-sized prompts (log-scaled, bucketed) so every `get_scenario` workload
-runs end-to-end on CPU engines.
+runs end-to-end on engines of the reduced model, on whatever device JAX
+offers (`chip_smoke.py` serves the published widths on a TPU).
 
 Long requests that the policy schedules across multiple replicas with fast
 SP are GANG-scheduled: the replicas map onto a host device mesh and prefill
@@ -38,6 +39,7 @@ os.environ.setdefault("XLA_FLAGS",
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, reduced_config
 from repro.core import (POLICY_NAMES, ClusterConfig, ExecutionModel,
                         Simulator, get_scenario, list_scenarios, make_policy)
@@ -118,6 +120,7 @@ def main() -> None:
         policies = tuple(dict.fromkeys(
             "pecsched/coord" if p == "pecsched" else p for p in policies))
 
+    enable_compile_cache()
     cfg = dataclasses.replace(
         reduced_config(get_config("mistral_7b"), layers=args.layers),
         dtype="float32", sliding_window=0)

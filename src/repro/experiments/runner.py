@@ -166,6 +166,14 @@ def _run_spec_for_pool(spec_dict: Dict) -> Dict:
     return run_spec(ExperimentSpec.from_dict(spec_dict))
 
 
+def _cpu_only_worker() -> None:
+    """Pool initializer: a simulator worker never claims the accelerator,
+    which belongs to one process (the parent may hold it)."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+
+
 # ---------------------------------------------------------------------------
 # sweep with on-disk cache
 # ---------------------------------------------------------------------------
@@ -235,7 +243,8 @@ def run_sweep(specs: Sequence[ExperimentSpec], *,
             os.environ["PYTHONPATH"] = (src + os.pathsep + env_path
                                         if env_path else src)
         ctx = multiprocessing.get_context("spawn")
-        with ProcessPoolExecutor(max_workers=workers, mp_context=ctx) as ex:
+        with ProcessPoolExecutor(max_workers=workers, mp_context=ctx,
+                                 initializer=_cpu_only_worker) as ex:
             for spec, summary in zip(
                     par, ex.map(_run_spec_for_pool,
                                 [s.to_dict() for s in par])):

@@ -1,11 +1,15 @@
 """Jit-friendly kernel wrappers with implementation dispatch.
 
 ``impl``:
-  "auto"   — Pallas on TPU, XLA elsewhere (CPU tests, dry-run lowering)
-  "xla"    — chunked online-softmax attention in pure lax (memory-bounded HLO;
-             this is what the dry-run lowers so memory_analysis stays sane)
-  "pallas" — the Pallas TPU kernels (interpret=True on CPU for validation)
-  "ref"    — naive full-materialization oracle (small shapes only)
+  "auto"             — Pallas on TPU, XLA elsewhere (CPU tests, dry-run
+                       lowering)
+  "xla"              — chunked online-softmax attention in pure lax
+                       (memory-bounded HLO; what the dry-run lowers so
+                       memory_analysis stays sane)
+  "pallas"           — the Pallas TPU kernels, always compiled for the chip
+  "pallas_interpret" — the same kernels in Pallas interpret mode (CPU
+                       validation); only ever by name
+  "ref"              — naive full-materialization oracle (small shapes only)
 """
 from __future__ import annotations
 
@@ -136,7 +140,7 @@ def attention(q, k, v, *, causal=True, sliding_window=0, q_offset=0,
         return fa.flash_attention(q, k, v, causal=causal,
                                   sliding_window=sliding_window,
                                   q_offset=q_offset, kv_len=kv_len, scale=scale,
-                                  interpret=(impl == "pallas_interpret" or not _on_tpu()))
+                                  interpret=impl == "pallas_interpret")
     raise ValueError(f"unknown impl {impl}")
 
 
@@ -156,7 +160,7 @@ def decode_attention(q, k, v, cache_len, *, sliding_window=0, impl="auto"):
     if impl in ("pallas", "pallas_interpret"):
         from repro.kernels import flash_decode as fd
         return fd.flash_decode(q, k, v, cache_len, sliding_window=sliding_window,
-                               interpret=(impl == "pallas_interpret" or not _on_tpu()))
+                               interpret=impl == "pallas_interpret")
     raise ValueError(f"unknown impl {impl}")
 
 
@@ -192,7 +196,7 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk=256, init_state=None,
         from repro.kernels import ssd_kernel as sk
         return sk.ssd_scan_pallas(x, dt, A, B, C, D, chunk=chunk,
                                   init_state=init_state, return_state=return_state,
-                                  interpret=(impl == "pallas_interpret" or not _on_tpu()))
+                                  interpret=impl == "pallas_interpret")
     return _ssd_chunked_xla(x, dt, A, B, C, D, chunk=chunk,
                             init_state=init_state, return_state=return_state)
 

@@ -163,7 +163,7 @@ def run_combo(arch: str, shape_name: str, *, multi_pod: bool,
     b_shard = {k: jax.NamedSharding(mesh, v) for k, v in bspecs.items()}
     batch = st.batch_structs(cfg, shape)
 
-    with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt_shape = st.opt_structs(params_shape)
             ospecs = shd.opt_specs(pspecs, opt_shape)

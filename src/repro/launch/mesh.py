@@ -6,17 +6,10 @@ jax device state.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:                # jax >= 0.4.38: explicit-sharding axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly Auto on every axis
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

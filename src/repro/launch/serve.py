@@ -1,6 +1,7 @@
 """Serving launcher: run the PecSched mini-cluster over a synthetic request
-stream with a reduced model (CPU) — the production path would swap in the
-full config + production mesh with the dry-run shardings.
+stream with a reduced model on the default JAX device — the production path
+would swap in the full config + production mesh with the dry-run shardings
+(`chip_smoke.py` serves the published widths on one TPU).
 
     PYTHONPATH=src python -m repro.launch.serve --arch mistral_7b --n 24
 """
@@ -12,6 +13,7 @@ import dataclasses
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, reduced_config
 from repro.configs.base import ARCH_IDS
 from repro.core.schedulers import POLICY_NAMES
@@ -40,6 +42,7 @@ def main() -> None:
         raise SystemExit("the real-execution engine demo targets the dense "
                          "family (see DESIGN.md); use examples/quickstart.py "
                          "for other families")
+    enable_compile_cache()
     cfg = dataclasses.replace(reduced_config(base, layers=4),
                               dtype="float32", sliding_window=0)
     params = init_params(jax.random.PRNGKey(0), cfg)

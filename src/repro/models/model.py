@@ -96,6 +96,13 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     return p
 
 
+def layer_at(layers: Params, i) -> Params:
+    """Layer `i` (may be a traced index) of the stacked layer parameters —
+    how a program runs a slice of layers without copying the slice."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), layers)
+
+
 # ===========================================================================
 # Full-sequence forward (train / prefill)
 # ===========================================================================
@@ -512,11 +519,17 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Dict[str, Any],
     else:
         raise ValueError(fam)
 
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("bd,dv->bv", x, params["lm_head"].astype(dt))
-    logits = _mask_padded_vocab(cfg, logits)
     cache["len"] = clen + 1
-    return logits, cache
+    return lm_logits(cfg, params, x), cache
+
+
+def lm_logits(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
+    """Final norm + LM head over hidden states (..., d) -> (..., V), padded
+    vocab masked — the head every serving path (prefill, decode, gang SP)
+    shares."""
+    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    logits = jnp.einsum("...d,dv->...v", x, params["lm_head"].astype(x.dtype))
+    return _mask_padded_vocab(cfg, logits)
 
 
 def param_count(params: Params) -> int:

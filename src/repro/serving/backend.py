@@ -83,6 +83,12 @@ _PREEMPTIBLE_KINDS = ("long_prefill", "long_decode")
 _BUCKETS = (4, 8, 12, 16, 24, 32, 48, 64, 96, 128, 192, 256)
 
 
+def _greedy(logits: jax.Array) -> int:
+    """First generated token from (1, V) prefill logits: one host transfer,
+    no device program (nothing to compile inside a measured window)."""
+    return int(np.argmax(np.asarray(logits)[0]))
+
+
 class EngineBackend(ExecutionBackend):
     """Drive any `make_policy` policy over real JAX ReplicaEngines."""
 
@@ -125,7 +131,7 @@ class EngineBackend(ExecutionBackend):
         # of evicted decode lanes, and cluster-token decode progress per rid
         self._parked_decode: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
         self._pdone: Dict[int, int] = {}
-        self._gang_runners: Dict[Tuple[int, str], GangSPRunner] = {}
+        self._gang_runners: Dict[int, GangSPRunner] = {}   # by degree
         self.generated: Dict[int, List[int]] = {}         # request rid -> tokens
         self.stats = Counter()
         self.measured_s = 0.0
@@ -192,7 +198,7 @@ class EngineBackend(ExecutionBackend):
                 continue
             mesh = make_gang_mesh(degree, self.cfg.num_heads)
             plan = plan_for_gang(self.cfg, cluster_input_len, mesh)
-            runner = self._runner_for(degree, plan.inner_impl)
+            runner = self._runner_for(degree)
             for n in sorted(set(lengths)):
                 st = runner.start(-1, np.zeros(int(n), np.int32), plan)
                 done = False
@@ -257,11 +263,11 @@ class EngineBackend(ExecutionBackend):
 
     # ---- timed execution primitives ----------------------------------
     def _timed(self, fn, *args):
+        """Run `fn` and charge its wall time, waiting for every array it
+        produced — the arrays inside a returned `PrefillState` /
+        `GangPrefillState` included (both are pytrees)."""
         t0 = time.perf_counter()
-        out = fn(*args)
-        leaves = jax.tree.leaves(out)
-        if leaves:
-            jax.block_until_ready(leaves[0])
+        out = jax.block_until_ready(fn(*args))
         dt = time.perf_counter() - t0
         self.measured_s += dt
         return out, dt
@@ -308,7 +314,7 @@ class EngineBackend(ExecutionBackend):
                                   record=req.is_long)
         logits, d = self._timed(eng.prefill_logits, st)
         dt += d
-        self.generated[req.rid] = [int(jnp.argmax(logits[0]))]
+        self.generated[req.rid] = [_greedy(logits)]
         self._kv[req.rid] = st
         if req.prefix_group is not None and st.host_tokens is not None:
             # park the full prompt KV in THIS engine's prefix cache (admit
@@ -330,13 +336,12 @@ class EngineBackend(ExecutionBackend):
             return 1
         return gang_degree(len(work.replica_ids), cap=self.sp_degree_cap)
 
-    def _runner_for(self, degree: int, strategy: str) -> GangSPRunner:
-        key = (degree, strategy)
-        r = self._gang_runners.get(key)
+    def _runner_for(self, degree: int) -> GangSPRunner:
+        r = self._gang_runners.get(degree)
         if r is None:
             mesh = make_gang_mesh(degree, self.cfg.num_heads)
-            r = GangSPRunner(self.cfg, self.params, mesh, strategy)
-            self._gang_runners[key] = r
+            r = GangSPRunner(self.cfg, self.params, mesh)
+            self._gang_runners[degree] = r
         return r
 
     def _start_gang(self, req: Request, degree: int) -> GangPrefillState:
@@ -344,14 +349,14 @@ class EngineBackend(ExecutionBackend):
         # strategy choice reflects the CLUSTER-scale request length — the
         # planner's four-combination search (§5.3), not the scale prompt
         plan = plan_for_gang(self.cfg, req.input_len, mesh)
-        runner = self._runner_for(degree, plan.inner_impl)
+        runner = self._runner_for(degree)
         st, _ = self._timed(runner.start, req.rid, self._prompt(req), plan)
         self.stats["gang_prefills"] += 1
         return st
 
     def _gang_quantum(self, st: GangPrefillState) -> Tuple[bool, float]:
         """One SP quantum: lpq x degree layers at equal per-device compute."""
-        runner = self._runner_for(st.degree, st.plan.inner_impl)
+        runner = self._runner_for(st.degree)
         lo = st.layer
         (_, done), d = self._timed(runner.quantum, st, self.lpq * st.degree)
         self.stats["sp_prefill_quanta"] += 1
@@ -365,14 +370,14 @@ class EngineBackend(ExecutionBackend):
         the home replica's paged pool."""
         req = work.requests[0]
         st = self._gangs[req.rid]
-        runner = self._runner_for(st.degree, st.plan.inner_impl)
+        runner = self._runner_for(st.degree)
         dt = 0.0
         while st.layer < self.cfg.num_layers:
             _, d = self._gang_quantum(st)
             dt += d
         logits, d = self._timed(runner.logits, st)
         dt += d
-        self.generated[req.rid] = [int(jnp.argmax(logits[0]))]
+        self.generated[req.rid] = [_greedy(logits)]
         k, v = runner.gather_kv(st)
         del self._gangs[req.rid]
         home = work.replica_ids[0]
@@ -765,7 +770,7 @@ class EngineBackend(ExecutionBackend):
                 self.sim.push(t + d, "ENGINE_STEP", work)
                 return
             logits, d2 = self._timed(eng.prefill_logits, st)
-            self.generated[req.rid] = [int(jnp.argmax(logits[0]))]
+            self.generated[req.rid] = [_greedy(logits)]
             self._kv[req.rid] = self._psessions.pop(req.rid)
             work.duration = t + d + d2 - work.start
             self.sim.push(t + d + d2, "DONE", work)

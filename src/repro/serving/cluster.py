@@ -1,5 +1,5 @@
 """Real-execution mini cluster: the full policy stack driving actual
-ReplicaEngines on CPU.
+ReplicaEngines on the default JAX device (a TPU chip, or the host CPU).
 
 Historically this module carried its own hardcoded 2-policy decision tree
 (a divergent reimplementation of FIFO/PecSched, including a `_find_idle`
@@ -58,6 +58,7 @@ class MiniCluster:
 
     def __init__(self, cfg: ModelConfig, params, *, n_engines: int = 2,
                  policy: str = "pecsched", max_len: int = 512,
+                 max_slots: int = 8,
                  long_threshold: int = 128, layers_per_quantum: int = 2,
                  clock: str = "measured", seed: int = 0,
                  enable_sp: bool = True, sp_degree_cap: int = 0,
@@ -71,14 +72,14 @@ class MiniCluster:
             n_short_decode_replicas=1 if pecfam else 0,
             max_batch_tokens=max(2 * max_len, 256),
             max_coloc_tokens=max_len,
-            max_decode_concurrency=8)
+            max_decode_concurrency=max_slots)
         # a tight target_prefill_s makes longs claim SP groups, which the
         # backend gang-schedules over the host device mesh when it can
         self.em = ExecutionModel(cfg, self.cc.replica_spec(),
                                  target_prefill_s=target_prefill_s)
         self._tok: Dict[int, np.ndarray] = {}
         self.backend = EngineBackend(
-            cfg, params, max_len=max_len,
+            cfg, params, max_len=max_len, max_slots=max_slots,
             layers_per_quantum=layers_per_quantum, clock=clock,
             max_new_cap=1 << 30,                   # honor each max_new exactly
             token_provider=lambda r: self._tok.get(r.rid), seed=seed,

@@ -21,15 +21,16 @@ batching at the iteration level.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.kernels import ops
-from repro.models import layers as L
 from repro.models import model as mdl
 from repro.serving.kvcache import PagedKVCache, PrefixHit
 
@@ -45,6 +46,10 @@ class SlotsFull(RuntimeError):
     """
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=("tokens", "x", "kv_k", "kv_v", "prefix_k",
+                                "prefix_v"),
+                   meta_fields=("rid", "layer", "host_tokens"))
 @dataclass
 class PrefillState:
     """Suspension state of a paused prefill (paper §5.1).
@@ -73,6 +78,53 @@ class PrefillState:
 
     def kv_bytes(self) -> int:
         return sum(a.size * a.dtype.itemsize for a in self.kv_k) * 2
+
+
+# ---- compiled programs -----------------------------------------------------
+# Module-level and keyed on the (frozen, hashable) config, so every engine of
+# one model shares one program per (prompt length, quantum size).  The
+# weights are arguments, never constants folded into a program.
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _embed(params, tokens, *, cfg: ModelConfig):
+    return params["embed"][tokens].astype(jnp.dtype(cfg.dtype))
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "n"))
+def _prefill_slice(params, x, lo, pk, pv, *, cfg: ModelConfig, n: int):
+    """Layers [lo, lo + n) over x (B, S, d).  With pk/pv ((n, KV, P, hd),
+    the reused prefix KV of these layers) x covers only the suffix: RoPE
+    starts at position P and attention runs over [prefix ‖ suffix] at query
+    offset P, through the same `_dense_layer` body as a full prefill."""
+    B, S, _ = x.shape
+    P = 0 if pk is None else pk.shape[2]
+    positions = jnp.broadcast_to(jnp.arange(P, P + S)[None], (B, S))
+
+    def body(x, inp):
+        i, pkl, pvl = inp
+        attn_fn = None
+        if pkl is not None:
+            def attn_fn(qh, kh, vh, *, causal, sliding_window):
+                k = jnp.concatenate([pkl[None].astype(kh.dtype), kh], axis=2)
+                v = jnp.concatenate([pvl[None].astype(vh.dtype), vh], axis=2)
+                return ops.attention(qh, k, v, causal=causal,
+                                     sliding_window=sliding_window,
+                                     q_offset=P, impl="xla")
+        return mdl._dense_layer(cfg, mdl.layer_at(params["layers"], i), x,
+                                positions, sliding_window=cfg.sliding_window,
+                                impl="xla", write_cache=True, attn_fn=attn_fn)
+    return jax.lax.scan(body, x, (lo + jnp.arange(n), pk, pv))
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _finalize(params, x, *, cfg: ModelConfig):
+    return mdl.lm_logits(cfg, params, x[:, -1])
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _decode(params, cache_k, cache_v, slot_len, tokens, *, cfg: ModelConfig):
+    cache = {"len": slot_len, "k": cache_k, "v": cache_v}
+    logits, cache = mdl.decode_step(cfg, params, cache, tokens, impl="xla")
+    return logits, cache["k"], cache["v"]
 
 
 class ReplicaEngine:
@@ -107,85 +159,6 @@ class ReplicaEngine:
             hd, dt)
         self.slot_rid: List[Optional[int]] = [None] * max_slots
         self._view = None                      # cached dense decode view
-        self._embed = jax.jit(self._embed_fn)
-        self._layer_slice = jax.jit(self._layer_slice_fn,
-                                    static_argnames=("lo", "hi"))
-        self._suffix_slice = jax.jit(self._suffix_slice_fn,
-                                     static_argnames=("lo", "hi"))
-        self._finalize = jax.jit(self._finalize_fn)
-        self._decode = jax.jit(self._decode_fn)
-
-    # ---- compiled pieces --------------------------------------------------
-    def _embed_fn(self, tokens):
-        x = self.params["embed"][tokens].astype(jnp.dtype(self.cfg.dtype))
-        return x
-
-    def _layer_slice_fn(self, x, *, lo: int, hi: int):
-        cfg = self.cfg
-        sub = jax.tree.map(lambda a: a[lo:hi], self.params["layers"])
-        B, S, _ = x.shape
-        positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-
-        def body(x, pl):
-            x, kv = mdl._dense_layer(cfg, pl, x, positions,
-                                     sliding_window=cfg.sliding_window,
-                                     impl="xla", write_cache=True)
-            return x, kv
-        x, kvs = jax.lax.scan(body, x, sub)
-        return x, kvs
-
-    def _suffix_slice_fn(self, x, pk, pv, *, lo: int, hi: int):
-        """Layer slice for a SUFFIX prefill: x covers only the uncached
-        suffix positions; pk/pv ((hi-lo), KV, P, hd) is the reused prefix
-        KV for these layers.  Mirrors `_dense_layer` exactly (same L.*
-        calls, same residual order) with attention over [prefix ‖ suffix]
-        at query offset P — the cache-hit path whose decoded tokens must
-        match a from-scratch prefill."""
-        cfg = self.cfg
-        sub = jax.tree.map(lambda a: a[lo:hi], self.params["layers"])
-        B, S, _ = x.shape
-        P = pk.shape[2]
-        H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-        positions = jnp.broadcast_to(jnp.arange(P, P + S)[None], (B, S))
-
-        def body(x, inp):
-            pl, pkl, pvl = inp
-            attn = pl["attn"]
-            h = L.rms_norm(x, pl["ln1"], cfg.norm_eps)
-            q = L.linear(h, attn["wq"], attn.get("bq")).reshape(B, S, H, hd)
-            k = L.linear(h, attn["wk"], attn.get("bk")).reshape(B, S, KV, hd)
-            v = L.linear(h, attn["wv"], attn.get("bv")).reshape(B, S, KV, hd)
-            q = L.rope(q, positions, cfg.rope_theta)
-            k = L.rope(k, positions, cfg.rope_theta)
-            qh = q.transpose(0, 2, 1, 3)
-            kh = k.transpose(0, 2, 1, 3)               # (B, KV, S, hd)
-            vh = v.transpose(0, 2, 1, 3)
-            k_all = jnp.concatenate([pkl[None].astype(kh.dtype), kh], axis=2)
-            v_all = jnp.concatenate([pvl[None].astype(vh.dtype), vh], axis=2)
-            o = ops.attention(qh, k_all, v_all, causal=True,
-                              sliding_window=cfg.sliding_window,
-                              q_offset=P, impl="xla")
-            o = o.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
-            x = x + L.linear(o, attn["wo"])
-            x = x + L.swiglu(L.rms_norm(x, pl["ln2"], cfg.norm_eps),
-                             pl["mlp"])
-            return x, L.KVCache(k=kh, v=vh)
-        x, kvs = jax.lax.scan(body, x, (sub, pk, pv))
-        return x, kvs
-
-    def _finalize_fn(self, x):
-        cfg = self.cfg
-        x = L.rms_norm(x, self.params["final_norm"], cfg.norm_eps)
-        logits = jnp.einsum("bsd,dv->bsv", x,
-                            self.params["lm_head"].astype(x.dtype))
-        return logits[:, -1]
-
-    def _decode_fn(self, cache_k, cache_v, slot_len, tokens):
-        cfg = self.cfg
-        cache = {"len": slot_len, "k": cache_k, "v": cache_v}
-        logits, cache = mdl.decode_step(cfg, self.params, cache, tokens,
-                                        impl="xla")
-        return logits, cache["k"], cache["v"], cache["len"]
 
     # ---- prefill (preemptible) ---------------------------------------------
     def start_prefill(self, rid: int, tokens: jnp.ndarray,
@@ -198,11 +171,11 @@ class ReplicaEngine:
         beyond P is embedded and computed — the prefix's KV is reused."""
         if prefix_k is not None:
             P = prefix_k.shape[2]
-            x = self._embed(tokens[:, P:])
+            x = _embed(self.params, tokens[:, P:], cfg=self.cfg)
             return PrefillState(rid=rid, tokens=tokens, x=x, layer=0,
                                 prefix_k=prefix_k, prefix_v=prefix_v,
                                 host_tokens=host_tokens)
-        x = self._embed(tokens)
+        x = _embed(self.params, tokens, cfg=self.cfg)
         return PrefillState(rid=rid, tokens=tokens, x=x, layer=0,
                             host_tokens=host_tokens)
 
@@ -210,11 +183,11 @@ class ReplicaEngine:
         """Run up to layers_per_quantum layers; returns (state, done)."""
         lo = st.layer
         hi = min(lo + self.lpq, self.cfg.num_layers)
+        pk = pv = None
         if st.prefix_k is not None:
-            x, kvs = self._suffix_slice(st.x, st.prefix_k[lo:hi],
-                                        st.prefix_v[lo:hi], lo=lo, hi=hi)
-        else:
-            x, kvs = self._layer_slice(st.x, lo=lo, hi=hi)
+            pk, pv = st.prefix_k[lo:hi], st.prefix_v[lo:hi]
+        x, kvs = _prefill_slice(self.params, st.x, lo, pk, pv, cfg=self.cfg,
+                                n=hi - lo)
         st.x = x
         for i in range(hi - lo):
             st.kv_k.append(kvs.k[i])
@@ -224,7 +197,7 @@ class ReplicaEngine:
 
     def prefill_logits(self, st: PrefillState) -> jnp.ndarray:
         assert st.layer == self.cfg.num_layers
-        return self._finalize(st.x)
+        return _finalize(self.params, st.x, cfg=self.cfg)
 
     # ---- resident KV (paged pool) ------------------------------------------
     def resident(self, rid: int) -> bool:
@@ -395,16 +368,18 @@ class ReplicaEngine:
         self._view = (ck, cv)
         return self._view
 
-    def decode_iteration(self, tokens: Dict[int, int]) -> Dict[int, int]:
+    def decode_logits(self, tokens: Dict[int, int]) -> jax.Array:
         """One continuous-batching iteration over the active slots.
-        tokens: slot -> last token id. Returns slot -> next token id."""
-        tok = jnp.zeros((self.max_slots,), jnp.int32)
+        tokens: slot -> last token id.  Appends each active slot's new KV
+        to the pool and returns the (max_slots, V) logits."""
+        tok = np.zeros((self.max_slots,), np.int32)
         for s, t in tokens.items():
-            tok = tok.at[s].set(t)
+            tok[s] = t
         cache_k, cache_v = self._dense_view()
         lens = self.slot_lengths()
-        slot_len = jnp.asarray(lens, jnp.int32)
-        logits, new_k, new_v, _ = self._decode(cache_k, cache_v, slot_len, tok)
+        logits, new_k, new_v = _decode(self.params, cache_k, cache_v,
+                                       jnp.asarray(lens, jnp.int32),
+                                       jnp.asarray(tok), cfg=self.cfg)
         # the updated dense cache carries the appended tokens (inactive
         # slots' writes land at masked positions, same as the pre-paged
         # engine) — keep it as the live view
@@ -419,8 +394,9 @@ class ReplicaEngine:
                 raise ValueError("decode past engine max_len")
             self.kvpool.append_token(rid, new_k[:, s, :, pos],
                                      new_v[:, s, :, pos])
-        out = {}
-        nxt = jnp.argmax(logits, -1)
-        for s in tokens:
-            out[s] = int(nxt[s])
-        return out
+        return logits
+
+    def decode_iteration(self, tokens: Dict[int, int]) -> Dict[int, int]:
+        """`decode_logits`, greedy: returns slot -> next token id."""
+        nxt = np.asarray(jnp.argmax(self.decode_logits(tokens), -1))
+        return {s: int(nxt[s]) for s in tokens}

@@ -9,32 +9,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-if hasattr(jax, "shard_map"):           # top-level export (jax >= ~0.4.38)
-    _shard_map_base = jax.shard_map
-else:                                    # older jax: experimental module
-    from jax.experimental.shard_map import shard_map as _shard_map_base
-
-# the replication-check kwarg was renamed check_rep -> check_vma at a
-# different version than the top-level export appeared, so key the adapter
-# on the actual signature, not on where the function lives
-import inspect as _inspect
-
-if "check_vma" in _inspect.signature(_shard_map_base).parameters:
-    shard_map = _shard_map_base
-else:
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True, **kw):
-        return _shard_map_base(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=check_vma,
-                               **kw)
-
-
-def axis_size(name) -> int:
-    """Static size of a mapped mesh axis; jax.lax.axis_size is recent —
-    psum of a constant is the classic equivalent and folds statically."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
 
 def merge_partials(o1: jax.Array, lse1: jax.Array,
                    o2: jax.Array, lse2: jax.Array):

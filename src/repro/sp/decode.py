@@ -17,8 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.sp.common import shard_map
-
 
 def distributed_decode_local(q, k, v, cache_len, *, seq_axes,
                              sliding_window: int = 0):
@@ -75,7 +73,7 @@ def distributed_decode_attention(q, k, v, cache_len, *, mesh: Mesh,
     seq = axes if len(axes) > 1 else axes[0]
     fn = functools.partial(distributed_decode_local, seq_axes=axes,
                            sliding_window=sliding_window)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(bspec, None, None), P(bspec, None, seq, None),
                   P(bspec, None, seq, None), P(bspec)),

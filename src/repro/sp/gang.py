@@ -29,11 +29,10 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.models import layers as L
-from repro.sp.common import shard_map
+from repro.models import model as mdl
 from repro.sp.hybrid import fast_sp_attention_local
 from repro.sp.planner import SPPlan, TPU_V5E, HardwareSpec, plan_fast_sp
 
@@ -84,16 +83,16 @@ def plan_for_gang(cfg: ModelConfig, input_len: int, mesh: Mesh,
 # ---------------------------------------------------------------------------
 # the shard_map layer quantum
 # ---------------------------------------------------------------------------
-def _sp_layer_slice_local(x, sub, *, cfg: ModelConfig, strategy: str):
+def _sp_layer_slice_local(x, layers, lo, *, cfg: ModelConfig, strategy: str,
+                          n: int):
     """Runs INSIDE shard_map.  x (1, s_loc, d) = this rank's sequence
-    shard; sub = the layer-slice params, replicated.  The layer body IS
-    `model._dense_layer` — projections, RoPE, residuals, MLP all shared
-    with the single-replica engine path — with the core attention swapped
-    for the hybrid SP kernel (outer ring + inner a2a/allgather) via the
-    `attn_fn` hook, and RoPE fed GLOBAL positions so shards agree with
-    the single-replica computation."""
-    from repro.models import model as mdl
-    pi = jax.lax.psum(1, INNER_AXIS)
+    shard; layers = the stacked layer params, replicated; layers
+    [lo, lo + n) run.  The layer body IS `model._dense_layer` — projections,
+    RoPE, residuals, MLP all shared with the single-replica engine path —
+    with the core attention swapped for the hybrid SP kernel (outer ring +
+    inner a2a/allgather) via the `attn_fn` hook, and RoPE fed GLOBAL
+    positions so shards agree with the single-replica computation."""
+    pi = jax.lax.axis_size(INNER_AXIS)
     oidx = jax.lax.axis_index(OUTER_AXIS)
     iidx = jax.lax.axis_index(INNER_AXIS)
     B, s_loc, d = x.shape
@@ -104,16 +103,43 @@ def _sp_layer_slice_local(x, sub, *, cfg: ModelConfig, strategy: str):
                                 outer_axes=OUTER_AXIS, inner_axis=INNER_AXIS,
                                 strategy=strategy)
 
-    def body(x, pl):
-        x, kv = mdl._dense_layer(cfg, pl, x, positions,
+    def body(x, i):
+        x, kv = mdl._dense_layer(cfg, mdl.layer_at(layers, i), x, positions,
                                  sliding_window=cfg.sliding_window,
                                  impl="xla", write_cache=True,
                                  attn_fn=attn_fn)
         return x, (kv.k, kv.v)
 
-    return jax.lax.scan(body, x, sub)
+    return jax.lax.scan(body, x, lo + jnp.arange(n))
 
 
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _embed(params, toks, *, cfg: ModelConfig):
+    return params["embed"][toks].astype(jnp.dtype(cfg.dtype))
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "mesh", "n", "strategy"))
+def _gang_slice(layers, x, lo, *, cfg: ModelConfig, mesh: Mesh, n: int,
+                strategy: str):
+    """Layers [lo, lo + n) of a sequence-sharded prefill on `mesh`; the
+    weights enter as arguments, replicated."""
+    seq = P(None, SEQ_AXES, None)
+    kv_seq = P(None, None, None, SEQ_AXES, None)
+    fn = functools.partial(_sp_layer_slice_local, cfg=cfg, strategy=strategy,
+                           n=n)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(seq, P(), P()),
+                         out_specs=(seq, (kv_seq, kv_seq)),
+                         check_vma=False)(x, layers, lo)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "s_real"))
+def _last_logits(params, x, *, cfg: ModelConfig, s_real: int):
+    return mdl.lm_logits(cfg, params, x[:, s_real - 1])
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=("tokens", "x", "kv_k", "kv_v"),
+                   meta_fields=("rid", "s_real", "layer", "degree", "plan"))
 @dataclass
 class GangPrefillState:
     """Suspension state of a gang-SP prefill (§5.1 x §5.3): the sharded
@@ -131,43 +157,19 @@ class GangPrefillState:
 
 
 class GangSPRunner:
-    """Compiled gang-SP prefill pipeline for one (model, mesh, strategy).
+    """Gang-SP prefill for one (model, mesh).
 
-    The EngineBackend keeps one runner per (degree, strategy); its jitted
-    pieces are shared by every long request the gang shape serves, so a
-    policy sweep pays the shard_map compilation once per prompt bucket."""
+    The EngineBackend keeps one runner per gang degree.  The weights are
+    placed replicated on the mesh once, here, and enter every program as
+    arguments; the inner strategy (`SPPlan.inner_impl` of each request's
+    plan) selects the compiled slice, so a policy sweep pays the shard_map
+    compilation once per (prompt bucket, strategy)."""
 
-    def __init__(self, cfg: ModelConfig, params, mesh: Mesh, strategy: str):
-        assert strategy in ("a2a", "allgather"), strategy
+    def __init__(self, cfg: ModelConfig, params, mesh: Mesh):
         self.cfg = cfg
-        self.params = params
         self.mesh = mesh
-        self.strategy = strategy
+        self.params = jax.device_put(params, NamedSharding(mesh, P()))
         self.degree = int(np.prod([mesh.shape[a] for a in SEQ_AXES]))
-        self._embed = jax.jit(
-            lambda toks: params["embed"][toks].astype(jnp.dtype(cfg.dtype)))
-        self._slice = jax.jit(self._slice_fn, static_argnames=("lo", "hi"))
-        self._logits = jax.jit(self._logits_fn, static_argnames=("s_real",))
-
-    # ------------------------------------------------------------------
-    def _slice_fn(self, x, *, lo: int, hi: int):
-        sub = jax.tree.map(lambda a: a[lo:hi], self.params["layers"])
-        seq = P(None, SEQ_AXES, None)
-        kv_seq = P(None, None, None, SEQ_AXES, None)
-        fn = functools.partial(_sp_layer_slice_local, cfg=self.cfg,
-                               strategy=self.strategy)
-        return shard_map(fn, mesh=self.mesh,
-                         in_specs=(seq, P()),
-                         out_specs=(seq, (kv_seq, kv_seq)),
-                         check_vma=False)(x, sub)
-
-    def _logits_fn(self, x, *, s_real: int):
-        cfg = self.cfg
-        last = jax.lax.dynamic_slice_in_dim(x, s_real - 1, 1, axis=1)
-        last = L.rms_norm(last, self.params["final_norm"], cfg.norm_eps)
-        logits = jnp.einsum("bsd,dv->bsv", last,
-                            self.params["lm_head"].astype(last.dtype))
-        return logits[:, -1]
 
     # ------------------------------------------------------------------
     def start(self, rid: int, tokens: np.ndarray,
@@ -178,18 +180,20 @@ class GangSPRunner:
         toks = np.asarray(tokens, np.int32).reshape(-1)
         s_real = int(toks.shape[0])
         pad = (-s_real) % self.degree
-        toks = np.pad(toks, (0, pad))[None]
-        x = self._embed(jnp.asarray(toks))
-        return GangPrefillState(rid=rid, tokens=jnp.asarray(toks),
-                                s_real=s_real, x=x, layer=0,
-                                degree=self.degree, plan=plan)
+        toks = jnp.asarray(np.pad(toks, (0, pad))[None])
+        return GangPrefillState(rid=rid, tokens=toks, s_real=s_real,
+                                x=_embed(self.params, toks, cfg=self.cfg),
+                                layer=0, degree=self.degree, plan=plan)
 
     def quantum(self, st: GangPrefillState,
                 layers: int) -> Tuple[GangPrefillState, bool]:
-        """Advance up to `layers` layers (the gang-scaled quantum)."""
+        """Advance up to `layers` layers (the gang-scaled quantum) with the
+        request's planned inner strategy."""
         lo = st.layer
         hi = min(lo + layers, self.cfg.num_layers)
-        x, (kh, vh) = self._slice(st.x, lo=lo, hi=hi)
+        x, (kh, vh) = _gang_slice(self.params["layers"], st.x, lo,
+                                  cfg=self.cfg, mesh=self.mesh, n=hi - lo,
+                                  strategy=st.plan.inner_impl)
         st.x = x
         st.kv_k.append(kh)
         st.kv_v.append(vh)
@@ -198,7 +202,8 @@ class GangSPRunner:
 
     def logits(self, st: GangPrefillState) -> jnp.ndarray:
         assert st.layer == self.cfg.num_layers
-        return self._logits(st.x, s_real=st.s_real)
+        return _last_logits(self.params, st.x, cfg=self.cfg,
+                            s_real=st.s_real)
 
     def gather_kv(self, st: GangPrefillState) -> Tuple[np.ndarray, np.ndarray]:
         """Pull the sequence-sharded per-layer KV to the host as contiguous

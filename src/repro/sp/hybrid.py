@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels import ops
-from repro.sp.common import axis_size, finalize, merge_partials, shard_map
+from repro.sp.common import finalize, merge_partials
 from repro.sp.inner import _merge_heads, _split_heads
 
 
@@ -41,9 +41,9 @@ def fast_sp_attention_local(q, k, v, *, outer_axes, inner_axis: Optional[str],
     """Runs INSIDE shard_map. q (B,H,s_loc,D), k/v (B,KV,s_loc,D); the global
     sequence is sharded over (outer_axes..., inner_axis), outer-major."""
     b, h, s_loc, d = q.shape
-    po = axis_size(outer_axes) if outer_axes else 1
+    po = jax.lax.axis_size(outer_axes) if outer_axes else 1
     oidx = jax.lax.axis_index(outer_axes) if outer_axes else 0
-    pi = axis_size(inner_axis) if inner_axis else 1
+    pi = jax.lax.axis_size(inner_axis) if inner_axis else 1
     iidx = jax.lax.axis_index(inner_axis) if inner_axis else 0
     seg = s_loc * pi                       # outer segment length
 
@@ -131,6 +131,6 @@ def fast_sp_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         fast_sp_attention_local, outer_axes=outer if outer else None,
         inner_axis=inner, strategy=strategy, causal=causal,
         sliding_window=sliding_window, scale=scale)
-    return shard_map(fn, mesh=mesh,
+    return jax.shard_map(fn, mesh=mesh,
                          in_specs=(spec_q, spec_q, spec_q),
                          out_specs=spec_q, check_vma=False)(q, k, v)

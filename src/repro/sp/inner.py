@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
-from repro.sp.common import axis_size
 
 
 def _split_heads(x: jax.Array, p: int, axis_name: str) -> jax.Array:
@@ -43,7 +42,7 @@ def a2a_attention(q, k, v, *, axis_name: str, causal: bool = True,
                   sliding_window: int = 0, q_offset: int = 0,
                   scale: Optional[float] = None,
                   return_lse: bool = False):
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     qh = _split_heads(q, p, axis_name)
     kh = _split_heads(k, p, axis_name)
     vh = _split_heads(v, p, axis_name)
@@ -64,7 +63,7 @@ def allgather_attention(q, k, v, *, axis_name: str, causal: bool = True,
                         sliding_window: int = 0, q_offset: int = 0,
                         scale: Optional[float] = None,
                         return_lse: bool = False):
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, h, s_loc, d = q.shape
     hp = h // p

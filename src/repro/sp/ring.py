@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
-from repro.sp.common import axis_size, finalize, merge_partials
+from repro.sp.common import finalize, merge_partials
 
 
 def ring_attention_local(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -25,7 +25,7 @@ def ring_attention_local(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          scale: Optional[float] = None) -> jax.Array:
     """Runs INSIDE shard_map. q/k/v (B, H|KV, S_local, D) = this rank's segment;
     global sequence = concat of segments along the axis, in axis order."""
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, h, s_loc, d = q.shape
     q_off = idx * s_loc

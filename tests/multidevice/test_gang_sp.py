@@ -78,7 +78,7 @@ def test_gang_prefill_and_scatter_match_single_replica(model, attn_strategy,
     mesh = make_gang_mesh(4, cfg.num_heads)
     plan = SPPlan(attn_strategy=attn_strategy, mlp_strategy=mlp_strategy,
                   est_time=1.0)
-    runner = GangSPRunner(cfg, params, mesh, plan.inner_impl)
+    runner = GangSPRunner(cfg, params, mesh)
     gst = runner.start(7, toks, plan)
     gdone = False
     while not gdone:

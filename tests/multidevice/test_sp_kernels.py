@@ -19,7 +19,6 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels import ref
 from repro.sp import (distributed_decode_attention, fast_sp_attention,
                       ring_attention_local)
-from repro.sp.common import shard_map
 
 pytestmark = pytest.mark.skipif(
     jax.device_count() < 8,
@@ -45,7 +44,7 @@ def test_ring_attention_matches_reference(qkv):
     mesh = jax.make_mesh((8,), ("data",))
     want = ref.mha_reference(q, k, v, causal=True)
     fn = functools.partial(ring_attention_local, axis_name="data", causal=True)
-    got = jax.jit(shard_map(
+    got = jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=(P(None, None, "data", None),) * 3,
         out_specs=P(None, None, "data", None), check_vma=False))(q, k, v)
     assert float(jnp.abs(want - got).max()) < TOL

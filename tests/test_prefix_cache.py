@@ -1,18 +1,17 @@
-"""Prefix-cache correctness: bit-exactness, refcount conservation, COW.
+"""Prefix-cache correctness: cache-hit equivalence, refcount conservation, COW.
 
 Three layers, mirroring the subsystem's own stack:
 
 * **Engine** — a cache-hit suffix prefill (`lookup_cached_prefix` ->
-  `start_prefill(prefix_k/v)` -> `admit` -> greedy decode) must be BIT
-  identical to a from-scratch prefill of the same prompt whenever the
-  donor prefill ran the same sequence shape (XLA compiles one program per
-  shape; same program + causal masking => the shared positions' KV is
-  bit-reproducible).  Across different donor shapes XLA may tile the same
-  reductions differently, so there the contract is the serving-visible
-  one: identical greedy decode tokens, logits equal to float32 tolerance.
-  Swept across block-boundary and partial-tail prefix lengths
-  (deterministically; a hypothesis-randomized twin runs when the optional
-  dep is installed).
+  `start_prefill(prefix_k/v)` -> `admit` -> greedy decode) must give the
+  same greedy decode tokens as a from-scratch prefill of the same prompt,
+  with logits equal to float32 tolerance (`LOGIT_TOL`).  The suffix
+  program is not the full-prefill program — it attends over the prefix KV
+  concatenated in front at a query offset — so XLA tiles its reductions
+  differently and bitwise equality is out of contract even when the
+  donor ran the same sequence shape.  Swept across block-boundary and
+  partial-tail prefix lengths and across donor shapes (deterministically;
+  a hypothesis-randomized twin runs when the optional dep is installed).
 
 * **Pool** — block refcounts conserve the pool under shared admits,
   copy-on-write appends, reserve headroom and LRU cache eviction: every
@@ -35,6 +34,11 @@ from repro.serving.engine import ReplicaEngine
 from repro.serving.kvcache import PagedKVCache
 
 BLOCK = 8
+#: float32 logits of order 1 after 2 layers at d_model 256: reduction
+#: reordering between the suffix and full-prefill programs moves them by
+#: about 1e-6 (2e-6 the largest seen); a logic fault (wrong position, stale
+#: or missing prefix KV) moves them by O(0.1)
+LOGIT_TOL = 1e-4
 
 
 @pytest.fixture(scope="module")
@@ -67,11 +71,10 @@ def _kv_of(st):
     return jnp.stack(st.kv_k, 0)[:, 0], jnp.stack(st.kv_v, 0)[:, 0]
 
 
-def _run_cache_vs_scratch(eng, a, b, want_hit, *, exact):
+def _run_cache_vs_scratch(eng, a, b, want_hit):
     """Decode `b` from scratch, then again through a cache hit against
-    `a`'s parked KV.  `exact=True` (same-shape donor) demands bit
-    equality; otherwise greedy tokens must match and logits agree to
-    float32 tolerance."""
+    `a`'s parked KV: greedy tokens must match and logits agree to
+    `LOGIT_TOL`."""
     # from-scratch reference FIRST, then forget it (its own blocks would
     # otherwise satisfy the lookup and mask the a-vs-b reuse under test)
     st = _full_prefill(eng, 100, b)
@@ -97,12 +100,8 @@ def _run_cache_vs_scratch(eng, a, b, want_hit, *, exact):
     while not done:
         st_c, done = eng.prefill_quantum(st_c)
     logits = eng.prefill_logits(st_c)
-    if exact:
-        assert jnp.array_equal(ref_logits, logits), \
-            "cache-hit logits diverged bitwise"
-    else:
-        np.testing.assert_allclose(np.asarray(ref_logits),
-                                   np.asarray(logits), atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(ref_logits), np.asarray(logits),
+                               atol=LOGIT_TOL, rtol=LOGIT_TOL)
     slot = eng.admit(2, st_c)
     toks = _greedy(eng, slot, int(jnp.argmax(logits[0])), 4)
     assert toks == ref_toks, "cache-hit decode diverged"
@@ -115,35 +114,35 @@ def _run_cache_vs_scratch(eng, a, b, want_hit, *, exact):
     (3 * BLOCK, 44),         # block-aligned multi-block share
 ])
 def test_cache_hit_decode_bit_exact(engine, shared, total):
-    """Same-shape donor: reuse must be bit-exact end to end."""
+    """Same-shape donor: reuse gives the from-scratch greedy tokens and
+    logits within LOGIT_TOL."""
     cfg, eng = engine
     rng = np.random.default_rng(7)
     a = rng.integers(0, cfg.vocab_size, total)
     b = np.concatenate([a[:shared],
                         rng.integers(0, cfg.vocab_size, total - shared)])
-    _run_cache_vs_scratch(eng, a, b, (shared // BLOCK) * BLOCK, exact=True)
+    _run_cache_vs_scratch(eng, a, b, (shared // BLOCK) * BLOCK)
 
 
 def test_cache_hit_reprompt_whole_prompt_guard_bit_exact(engine):
     """Re-sending a cached prompt verbatim: the lookup must trim the hit
     to leave at least one live suffix token (prefill_logits needs a real
-    last-position hidden state) and the result is still bit-exact."""
+    last-position hidden state) and the result still matches."""
     cfg, eng = engine
     rng = np.random.default_rng(11)
     a = rng.integers(0, cfg.vocab_size, 44)
-    _run_cache_vs_scratch(eng, a, a.copy(), 40, exact=True)
+    _run_cache_vs_scratch(eng, a, a.copy(), 40)
 
 
 def test_cache_hit_cross_shape_decode_identical(engine):
     """Cross-shape reuse (the chat_multiturn pattern: the donor turn was
-    shorter than the consumer): XLA tiles per-shape, so bitwise equality
-    is out of contract — but the serving-visible outputs must agree:
-    identical greedy decode, logits to float32 tolerance."""
+    shorter than the consumer): identical greedy decode, logits within
+    LOGIT_TOL."""
     cfg, eng = engine
     rng = np.random.default_rng(13)
     a = rng.integers(0, cfg.vocab_size, 40)
     b = np.concatenate([a[:24], rng.integers(0, cfg.vocab_size, 20)])
-    _run_cache_vs_scratch(eng, a, b, 24, exact=False)
+    _run_cache_vs_scratch(eng, a, b, 24)
 
 
 def test_cache_hit_bit_exact_random_lengths(engine):
@@ -164,8 +163,7 @@ def test_cache_hit_bit_exact_random_lengths(engine):
         a = rng.integers(0, cfg.vocab_size, 44)
         b = np.concatenate([a[:shared],
                             rng.integers(0, cfg.vocab_size, 44 - shared)])
-        _run_cache_vs_scratch(eng, a, b, (shared // BLOCK) * BLOCK,
-                              exact=True)
+        _run_cache_vs_scratch(eng, a, b, (shared // BLOCK) * BLOCK)
 
     prop()
 
