@@ -1,0 +1,19 @@
+"""Published peaks of each chip, keyed by JAX's `device_kind`.
+
+Source: Google Cloud documentation, "TPU v5e" (system architecture): per
+chip 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s.
+A device that is not in the table is an error, never a default.
+"""
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9,
+                    "hbm_bytes": 16e9},
+}
+
+
+def peak_for(kind: str) -> dict:
+    if kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {kind!r}; "
+                       f"known: {sorted(PEAKS)}")
+    return PEAKS[kind]

@@ -1,0 +1,10 @@
+"""Share of the traced window in which no operation ran on the chip: one
+less the union of device-operation intervals over the window, mean over
+the cell's chips."""
+
+
+def read(ctx):
+    t = ctx.trace
+    if t is None or not t.window_s:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
