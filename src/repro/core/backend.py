@@ -80,6 +80,10 @@ class ExecutionBackend:
     #: True if the driver must call `finish(t, work)` right before the
     #: policy's on_done (backends that execute lazily at completion time).
     needs_finish = False
+    #: host seconds spent inside the backend's calls from the loop and the
+    #: policy, which `Simulator.sched_time` leaves out (0 where they are
+    #: not measured)
+    host_s = 0.0
 
     def bind(self, sim) -> None:
         self.sim = sim

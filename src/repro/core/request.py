@@ -50,6 +50,11 @@ class Request:
     first_token: Optional[float] = None
     finish: Optional[float] = None
     n_preemptions: int = 0                  # times THIS request was suspended
+    # preemptions' suspensions (§5.1), on the loop's clock: seconds closed
+    # so far, and the start of the open one (None when running); churn
+    # evacuations are not preemptions and are left out
+    suspended_s: float = 0.0
+    paused_at: Optional[float] = None
     prefill_remaining: float = 0.0          # seconds of prefill work left
     shed: bool = False                      # dropped by an SLO-aware policy
     replicas: List[int] = field(default_factory=list)

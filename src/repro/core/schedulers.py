@@ -534,6 +534,13 @@ class PriorityPolicy(FIFOPolicy):
 # ===========================================================================
 # PecSched (§5) with ablation flags
 # ===========================================================================
+def _end_suspension(req: Request, t: float) -> None:
+    """Close `req`'s open preemption suspension at `t`, if it has one."""
+    if req.paused_at is not None:
+        req.suspended_s += t - req.paused_at
+        req.paused_at = None
+
+
 @dataclass
 class LongState:
     req: Request
@@ -807,11 +814,13 @@ class PecSchedPolicy(BasePolicy):
         self._paused[st.req.rid] = st
         st.req.phase = Phase.PAUSED
         st.req.n_preemptions += 1
+        st.req.paused_at = t
         self.preemption_events += 1
 
     def _resume_long(self, t, st: LongState):
         st.paused = False
         del self._paused[st.req.rid]
+        _end_suspension(st.req, t)
         if st.phase == "prefill" or not self.coloc:
             self._victims[st.req.rid] = st
         if st.phase == "prefill":
@@ -1063,6 +1072,7 @@ class PecSchedPolicy(BasePolicy):
 
     def _restart_long(self, t, st: LongState):
         req = st.req
+        _end_suspension(req, t)
         self.longs.pop(req.rid, None)
         self._victims.pop(req.rid, None)
         self._paused.pop(req.rid, None)

@@ -25,8 +25,8 @@ The event loop is built for 100 K+-request traces:
   applied before a single `policy.dispatch()` pass, so simultaneous
   completions trigger one placement scan, not one per event.
 * **Profile counters**: `Simulator.profile()` reports events, pushes,
-  cancels, dispatch passes, peak heap size, wall/policy time and events/sec
-  (surfaced by `benchmarks/simulator_scale.py --profile` and
+  cancels, dispatch passes, peak heap size, wall/policy/backend time and
+  events/sec (surfaced by `benchmarks/simulator_scale.py --profile` and
   `examples/trace_replay.py --profile`).
 """
 from __future__ import annotations
@@ -192,7 +192,11 @@ class Simulator:
         self.heap = EventHeap()
         self._work_entries: Dict[int, Entry] = {}   # wid -> pending entry
         self.now = 0.0
-        self.sched_time = 0.0           # wall-clock spent in policy decisions
+        #: wall-clock spent in policy decisions: on_arrival, on_done,
+        #: dispatch and fleet steps, less the backend's own host seconds
+        #: inside the calls they make (`backend.host_s`)
+        self.sched_time = 0.0
+        self.backend_time = 0.0         # wall-clock inside backend calls
         self.run_time = 0.0             # wall-clock of the whole run()
         self.n_dispatches = 0           # dispatch passes actually run
         self.n_events = 0               # events applied (arrivals + dones)
@@ -262,8 +266,9 @@ class Simulator:
         dispatch = self.policy.dispatch
         needs_dispatch = self.policy.needs_dispatch
         elide = self.elide_dispatch
-        backend_event = self.backend.on_event
-        finish = self.backend.finish if self.backend.needs_finish else None
+        backend = self.backend
+        backend_event = backend.on_event
+        finish = backend.finish if backend.needs_finish else None
         arr_iter = iter(requests)
         next_req = next(arr_iter, None)
         arrivals: List[Request] = []
@@ -297,6 +302,7 @@ class Simulator:
             if arrivals and t > self.last_arrival:
                 self.last_arrival = t       # generator input: track inline
             t0 = _time.perf_counter()
+            h0 = backend.host_s
             n_policy_events = len(arrivals)
             self.n_stream_arrivals += n_policy_events
             self.n_events += n_policy_events
@@ -347,7 +353,9 @@ class Simulator:
                     fleet_step(t)
                 dispatch(t)
                 self.n_dispatches += 1
-            self.sched_time += _time.perf_counter() - t0
+            in_backend = backend.host_s - h0
+            self.backend_time += in_backend
+            self.sched_time += _time.perf_counter() - t0 - in_backend
         self.policy.finalize(self.now)
         self.run_time = _time.perf_counter() - wall0
         return self.policy.summary(self.now)
@@ -373,7 +381,8 @@ class Simulator:
             "index_rescans": getattr(index, "n_rescans", 0),
             "wall_s": self.run_time,
             "policy_s": self.sched_time,
-            "loop_s": self.run_time - self.sched_time,
+            "backend_s": self.backend_time,
+            "loop_s": self.run_time - self.sched_time - self.backend_time,
             "events_per_sec": self.n_events / max(self.run_time, 1e-9),
         }
 
@@ -387,5 +396,6 @@ def format_profile(p: Dict) -> str:
             "peak_heap_slots={peak_heap_slots} "
             "index(queries/rescans)={index_queries}/{index_rescans} "
             "wall={wall_s:.2f}s "
-            "(policy {policy_s:.2f}s / loop {loop_s:.2f}s) "
+            "(policy {policy_s:.2f}s / backend {backend_s:.2f}s / "
+            "loop {loop_s:.2f}s) "
             "events/sec={events_per_sec:,.0f}".format(**p))

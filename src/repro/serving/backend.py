@@ -52,6 +52,7 @@ while jit recompiles stay bounded.
 """
 from __future__ import annotations
 
+import functools
 import math
 import time
 from collections import Counter, deque
@@ -61,6 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.core.backend import ExecutionBackend
 from repro.core.request import Request
@@ -87,6 +89,24 @@ def _greedy(logits: jax.Array) -> int:
     """First generated token from (1, V) prefill logits: one host transfer,
     no device program (nothing to compile inside a measured window)."""
     return int(np.argmax(np.asarray(logits)[0]))
+
+
+def _call(start):
+    """Marks a method the event loop or a policy calls into the backend.
+    Its host seconds add to `host_s`, which `Simulator.sched_time` leaves
+    out, and tokens it generates are stamped from `start(self, *args)`,
+    the virtual time at which the call's work begins."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(self, *args):
+            t0 = time.perf_counter()
+            self._vstart, self._vdt = start(self, *args), 0.0
+            try:
+                return fn(self, *args)
+            finally:
+                self.host_s += time.perf_counter() - t0
+        return call
+    return wrap
 
 
 class EngineBackend(ExecutionBackend):
@@ -133,8 +153,17 @@ class EngineBackend(ExecutionBackend):
         self._pdone: Dict[int, int] = {}
         self._gang_runners: Dict[int, GangSPRunner] = {}   # by degree
         self.generated: Dict[int, List[int]] = {}         # request rid -> tokens
+        #: request rid -> virtual time of each generated token: the first
+        #: at prefill completion, each later one at its decode step's end
+        #: (the Work's start plus the measured seconds of the Work so far)
+        self.token_times: Dict[int, List[float]] = {}
         self.stats = Counter()
         self.measured_s = 0.0
+        #: host seconds spent inside calls from the loop and the policy
+        self.host_s = 0.0
+        self._vstart = self._vdt = 0.0     # the current call's clock
+        #: the process's span and counter aggregates (`repro.obs`)
+        self.obs = obs.TRACER
         #: degree -> measured seconds per layer (1 = single-replica path);
         #: accumulates across reset() like the engines' jit caches, so a
         #: sweep's calibration sees every run
@@ -156,8 +185,11 @@ class EngineBackend(ExecutionBackend):
         self._parked_decode.clear()
         self._pdone.clear()
         self.generated.clear()
+        self.token_times.clear()
         self.stats = Counter()
         self.measured_s = 0.0
+        self.host_s = 0.0
+        self.obs.reset()
 
     def prompt_len(self, req: Request) -> int:
         """Engine-side prompt length this request will execute with."""
@@ -270,7 +302,16 @@ class EngineBackend(ExecutionBackend):
         out = jax.block_until_ready(fn(*args))
         dt = time.perf_counter() - t0
         self.measured_s += dt
+        self._vdt += dt
         return out, dt
+
+    def _first_token(self, rid: int, logits: jax.Array) -> None:
+        self.generated[rid] = [_greedy(logits)]
+        self.token_times[rid] = [self._vstart + self._vdt]
+
+    def _next_token(self, rid: int, tok: int) -> None:
+        self.generated[rid].append(tok)
+        self.token_times[rid].append(self._vstart + self._vdt)
 
     def _start_prefill(self, eng: ReplicaEngine, req: Request) -> PrefillState:
         prompt = self._prompt(req)
@@ -314,7 +355,7 @@ class EngineBackend(ExecutionBackend):
                                   record=req.is_long)
         logits, d = self._timed(eng.prefill_logits, st)
         dt += d
-        self.generated[req.rid] = [_greedy(logits)]
+        self._first_token(req.rid, logits)
         self._kv[req.rid] = st
         if req.prefix_group is not None and st.host_tokens is not None:
             # park the full prompt KV in THIS engine's prefix cache (admit
@@ -377,7 +418,7 @@ class EngineBackend(ExecutionBackend):
             dt += d
         logits, d = self._timed(runner.logits, st)
         dt += d
-        self.generated[req.rid] = [_greedy(logits)]
+        self._first_token(req.rid, logits)
         k, v = runner.gather_kv(st)
         del self._gangs[req.rid]
         home = work.replica_ids[0]
@@ -462,7 +503,7 @@ class EngineBackend(ExecutionBackend):
                 dt += d
                 self.stats["decode_iters"] += 1
                 for s, tok in out.items():
-                    self.generated[admitted[s].rid].append(tok)
+                    self._next_token(admitted[s].rid, tok)
                     toks[s] = tok
                     remaining[s] -= 1
             for s in admitted:
@@ -517,7 +558,7 @@ class EngineBackend(ExecutionBackend):
             dt += d
             self.stats["decode_iters"] += 1
             last = out[slot]
-            self.generated[rid].append(last)
+            self._next_token(rid, last)
         if final:
             eng.evict(slot)
             self._pdone.pop(rid, None)
@@ -590,6 +631,7 @@ class EngineBackend(ExecutionBackend):
     # ------------------------------------------------------------------
     # ExecutionBackend interface
     # ------------------------------------------------------------------
+    @_call(lambda self, work: work.start)
     def submit(self, work: Work) -> None:
         t = work.start
         if work.kind in _EAGER_KINDS:
@@ -620,6 +662,7 @@ class EngineBackend(ExecutionBackend):
         else:
             self.sim.push(t, "ENGINE_STEP", work)
 
+    @_call(lambda self, work: self.sim.now)
     def decode_inline(self, work: Work) -> None:
         """/Dis colocated shorts finish with decode modeled inline by the
         policy; run that decode for real (on the colocation group's first
@@ -649,6 +692,7 @@ class EngineBackend(ExecutionBackend):
                     f"{resident}")
         self.stats["role_flips"] += 1
 
+    @_call(lambda self, t, rid: t)
     def reclaim_replica(self, t: float, rid: int) -> Dict[str, int]:
         """Spot eviction of replica `rid`: park every piece of KV physically
         resident on its engine so migrated requests resume elsewhere, then
@@ -689,6 +733,7 @@ class EngineBackend(ExecutionBackend):
         self.stats["evacuated_blocks"] += blocks
         return {"parked_sessions": parked, "evacuated_blocks": blocks}
 
+    @_call(lambda self, work: self.sim.now)
     def cancel(self, work: Work) -> bool:
         ok = self.sim.cancel(work)
         if ok and self.clock == "analytic":
@@ -730,12 +775,13 @@ class EngineBackend(ExecutionBackend):
             dt += d
             self.stats["decode_iters"] += 1
             tok = out[sess["slot"]]
-            self.generated[req.rid].append(tok)
+            self._next_token(req.rid, tok)
             sess["last"] = tok
             sess["remaining"] -= 1
         return dt
 
     # ---- measured clock: quantum events ------------------------------
+    @_call(lambda self, t, kind, work: t)
     def on_event(self, t: float, kind: str, work: Work) -> None:
         assert kind == "ENGINE_STEP", kind
         req = work.requests[0]
@@ -757,20 +803,14 @@ class EngineBackend(ExecutionBackend):
                 work.duration = max(t - work.start, 0.0)
                 self.sim.push(t, "DONE", work)
                 return
+            d = self._prefill_quanta(
+                eng, st, min(st.layer + self.lpq, self.cfg.num_layers),
+                record=True)
             if st.layer < self.cfg.num_layers:
-                lo = st.layer
-                (_, done), d = self._timed(eng.prefill_quantum, st)
-                self.stats["prefill_quanta"] += 1
-                if st.layer > lo:          # a long on the single-replica path
-                    self.sp_timings.setdefault(1, []).append(
-                        d / (st.layer - lo))
-            else:
-                done, d = True, 0.0
-            if not done:
                 self.sim.push(t + d, "ENGINE_STEP", work)
                 return
             logits, d2 = self._timed(eng.prefill_logits, st)
-            self.generated[req.rid] = [_greedy(logits)]
+            self._first_token(req.rid, logits)
             self._kv[req.rid] = self._psessions.pop(req.rid)
             work.duration = t + d + d2 - work.start
             self.sim.push(t + d + d2, "DONE", work)
@@ -794,6 +834,7 @@ class EngineBackend(ExecutionBackend):
                 self.sim.push(t + d, "ENGINE_STEP", work)
 
     # ---- analytic clock: lazy completion ------------------------------
+    @_call(lambda self, t, work: t)
     def finish(self, t: float, work: Work) -> None:
         if work.kind == "long_prefill":
             req = work.requests[0]

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.kernels import ops
 from repro.models import model as mdl
@@ -181,18 +182,21 @@ class ReplicaEngine:
 
     def prefill_quantum(self, st: PrefillState) -> Tuple[PrefillState, bool]:
         """Run up to layers_per_quantum layers; returns (state, done)."""
-        lo = st.layer
-        hi = min(lo + self.lpq, self.cfg.num_layers)
-        pk = pv = None
-        if st.prefix_k is not None:
-            pk, pv = st.prefix_k[lo:hi], st.prefix_v[lo:hi]
-        x, kvs = _prefill_slice(self.params, st.x, lo, pk, pv, cfg=self.cfg,
-                                n=hi - lo)
-        st.x = x
-        for i in range(hi - lo):
-            st.kv_k.append(kvs.k[i])
-            st.kv_v.append(kvs.v[i])
-        st.layer = hi
+        with obs.span("prefill_quantum", rid=st.rid):
+            lo = st.layer
+            hi = min(lo + self.lpq, self.cfg.num_layers)
+            pk = pv = None
+            if st.prefix_k is not None:
+                pk, pv = st.prefix_k[lo:hi], st.prefix_v[lo:hi]
+            with obs.span("prefill_quantum/program"):
+                x, kvs = _prefill_slice(self.params, st.x, lo, pk, pv,
+                                        cfg=self.cfg, n=hi - lo)
+            st.x = x
+            with obs.span("prefill_quantum/kv_split"):
+                for i in range(hi - lo):
+                    st.kv_k.append(kvs.k[i])
+                    st.kv_v.append(kvs.v[i])
+            st.layer = hi
         return st, hi == self.cfg.num_layers
 
     def prefill_logits(self, st: PrefillState) -> jnp.ndarray:
@@ -316,16 +320,22 @@ class ReplicaEngine:
                 f"KV pool cannot reserve a decode lane for request {rid}: "
                 f"{len(self.kvpool.free)} of {self.kvpool.n_blocks} "
                 f"blocks free, {self.blocks_per_seq} needed")
-        k = jnp.stack(st.kv_k, 0)[:, 0]      # (L, KV, S, hd)
-        v = jnp.stack(st.kv_v, 0)[:, 0]
-        if st.prefix_k is not None:          # re-assemble FULL-sequence KV
-            k = jnp.concatenate([st.prefix_k.astype(k.dtype), k], axis=2)
-            v = jnp.concatenate([st.prefix_v.astype(v.dtype), v], axis=2)
-        self.kvpool.admit(rid, k, v, tokens=st.host_tokens)
-        self.kvpool.reserve(rid, self.max_len)
-        slot = free[0]
-        self.slot_rid[slot] = rid
-        self._invalidate_view()
+        with obs.span("admit", rid=rid):
+            with obs.span("admit/assemble"):
+                k = jnp.stack(st.kv_k, 0)[:, 0]      # (L, KV, S, hd)
+                v = jnp.stack(st.kv_v, 0)[:, 0]
+                if st.prefix_k is not None:      # re-assemble FULL-sequence KV
+                    k = jnp.concatenate([st.prefix_k.astype(k.dtype), k],
+                                        axis=2)
+                    v = jnp.concatenate([st.prefix_v.astype(v.dtype), v],
+                                        axis=2)
+            with obs.span("admit/pool_write"):
+                self.kvpool.admit(rid, k, v, tokens=st.host_tokens)
+                self.kvpool.reserve(rid, self.max_len)
+            obs.count("admit/tokens", S)
+            slot = free[0]
+            self.slot_rid[slot] = rid
+            self._invalidate_view()
         return slot
 
     def evict(self, slot: int) -> None:
@@ -351,35 +361,39 @@ class ReplicaEngine:
         per-token cost stays proportional to the step, not the pool."""
         if self._view is not None:
             return self._view
-        cfg = self.cfg
-        nl, KV, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-        dt = jnp.dtype(cfg.dtype)
-        ck = jnp.zeros((nl, self.max_slots, KV, self.max_len, hd), dt)
-        cv = jnp.zeros((nl, self.max_slots, KV, self.max_len, hd), dt)
-        for s, rid in enumerate(self.slot_rid):
-            if rid is None or not self.resident(rid):
-                continue
-            k, v = self.kvpool.gather(rid)
-            pad = self.max_len - k.shape[2]
-            k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
-            v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
-            ck = ck.at[:, s].set(k)
-            cv = cv.at[:, s].set(v)
-        self._view = (ck, cv)
+        with obs.span("decode_iteration/view"):
+            cfg = self.cfg
+            nl, KV, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
+            dt = jnp.dtype(cfg.dtype)
+            ck = jnp.zeros((nl, self.max_slots, KV, self.max_len, hd), dt)
+            cv = jnp.zeros((nl, self.max_slots, KV, self.max_len, hd), dt)
+            for s, rid in enumerate(self.slot_rid):
+                if rid is None or not self.resident(rid):
+                    continue
+                k, v = self.kvpool.gather(rid)
+                pad = self.max_len - k.shape[2]
+                k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                ck = ck.at[:, s].set(k)
+                cv = cv.at[:, s].set(v)
+            self._view = (ck, cv)
         return self._view
 
     def decode_logits(self, tokens: Dict[int, int]) -> jax.Array:
         """One continuous-batching iteration over the active slots.
         tokens: slot -> last token id.  Appends each active slot's new KV
         to the pool and returns the (max_slots, V) logits."""
-        tok = np.zeros((self.max_slots,), np.int32)
-        for s, t in tokens.items():
-            tok[s] = t
-        cache_k, cache_v = self._dense_view()
-        lens = self.slot_lengths()
-        logits, new_k, new_v = _decode(self.params, cache_k, cache_v,
-                                       jnp.asarray(lens, jnp.int32),
-                                       jnp.asarray(tok), cfg=self.cfg)
+        with obs.span("decode_iteration/prep"):
+            tok = np.zeros((self.max_slots,), np.int32)
+            for s, t in tokens.items():
+                tok[s] = t
+            cache_k, cache_v = self._dense_view()
+            lens = self.slot_lengths()
+            slot_len, tok = jnp.asarray(lens, jnp.int32), jnp.asarray(tok)
+        with obs.span("decode_iteration/program"):
+            logits, new_k, new_v = _decode(self.params, cache_k, cache_v,
+                                           slot_len, tok, cfg=self.cfg)
+        obs.count("decode_iteration/slots", len(tokens))
         # the updated dense cache carries the appended tokens (inactive
         # slots' writes land at masked positions, same as the pre-paged
         # engine) — keep it as the live view
@@ -387,16 +401,20 @@ class ReplicaEngine:
         # append the new token's KV back to the pool — active slots only.
         # Slots reserved their full budget at admission, so this never
         # allocates and cannot fail mid-iteration.
-        for s in tokens:
-            rid = self.slot_rid[s]
-            pos = lens[s]
-            if pos >= self.max_len:
-                raise ValueError("decode past engine max_len")
-            self.kvpool.append_token(rid, new_k[:, s, :, pos],
-                                     new_v[:, s, :, pos])
+        with obs.span("decode_iteration/append"):
+            for s in tokens:
+                rid = self.slot_rid[s]
+                pos = lens[s]
+                if pos >= self.max_len:
+                    raise ValueError("decode past engine max_len")
+                self.kvpool.append_token(rid, new_k[:, s, :, pos],
+                                         new_v[:, s, :, pos])
         return logits
 
     def decode_iteration(self, tokens: Dict[int, int]) -> Dict[int, int]:
         """`decode_logits`, greedy: returns slot -> next token id."""
-        nxt = np.asarray(jnp.argmax(self.decode_logits(tokens), -1))
-        return {s: int(nxt[s]) for s in tokens}
+        with obs.span("decode_iteration"):
+            logits = self.decode_logits(tokens)
+            with obs.span("decode_iteration/readback"):
+                nxt = np.asarray(jnp.argmax(logits, -1))
+                return {s: int(nxt[s]) for s in tokens}
