@@ -10,7 +10,11 @@ general and one dedicated decode engine.  A long prompt is preempted by
 shorts that arrive inside its prefill window; its tokens must equal an
 uninterrupted run's.  One short's logits after prefill and after each
 paged decode step are compared with `models.model.forward` on float32
-copies of the weights.
+copies of the weights.  Then the engine's decode program, on 4 slots of
+9136 tokens with one live slot of 512, 2048 and 9000 tokens: the Pallas
+kernel's logits against the XLA paged path's and against
+`models.model.decode_step` on a dense cache of the same KV, and each
+one's time per step.
 
 Four chips (`--chips 4`): a long prefill that the policy gang-schedules at
 degree 4 on a (2, 2) mesh, compared with the same prompt on one chip.
@@ -40,9 +44,11 @@ import jax.numpy as jnp  # noqa: E402
 from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.configs import reduced_config  # noqa: E402
 from repro.configs.mistral_7b import CONFIG, PIPELINE_STAGE  # noqa: E402
+from repro.kernels import ops  # noqa: E402
 from repro.models import init_params  # noqa: E402
 from repro.models import model as mdl  # noqa: E402
 from repro.serving import MiniCluster, ServeRequest  # noqa: E402
+from repro.serving import engine as engine_mod  # noqa: E402
 from repro.serving.engine import ReplicaEngine  # noqa: E402
 from repro.sp.gang import OUTER_AXIS, INNER_AXIS, plan_for_gang  # noqa: E402
 
@@ -80,6 +86,10 @@ class Size:
     long_threshold: int
     long_new: int = 8
     short_new: int = 4
+    #: the decode program's slots (the benchmark's), and the live lengths
+    #: of the one active slot it is timed at
+    decode_max_len: int = 9136
+    decode_lens: tuple = (512, 2048, 9000)
 
 
 FULL = Size(PIPELINE_STAGE, max_slots=4, max_len=4096, long_len=3584,
@@ -90,7 +100,7 @@ TINY = Size(dataclasses.replace(
                                n_kv=2, d_ff=512, vocab=512),
                 dtype="bfloat16"),
             max_slots=4, max_len=256, long_len=192, short_lens=(24, 40, 24, 40),
-            long_threshold=128)
+            long_threshold=128, decode_max_len=272, decode_lens=(24, 100, 250))
 
 
 class CompileCounter:
@@ -288,6 +298,87 @@ def reference_phase(size: Size, params, short: ServeRequest,
           "the relative-L2 tolerance fails the fp8-e4m3 canary")
 
 
+def paged_decode_phase(size: Size, params, seed: int) -> None:
+    """The decode program on one live slot of each of `decode_lens` tokens
+    (random KV) in an engine of `max_slots` slots x `decode_max_len`: the
+    Pallas kernel (interpret mode off the chip), the XLA paged path and
+    `decode_step` over a dense cache of the same KV, each timed per step."""
+    cfg, slots, max_len = size.cfg, size.max_slots, size.decode_max_len
+    kernel = "pallas" if jax.default_backend() == "tpu" else \
+        "pallas_interpret"
+    # room for one bound slot: the kernel reads only the live pages, so
+    # the pool's size does not change what a step costs
+    eng = ReplicaEngine(cfg, params, max_slots=slots, max_len=max_len,
+                        n_blocks=2 * -(-max_len // 16))
+    if jax.default_backend() == "tpu":
+        picked = ops.paged_decode_impl(cfg.head_dim, cfg.sliding_window)
+        check(picked == "pallas", "the engine decodes with the Pallas "
+              f"kernel on the chip ({picked})")
+    dense_step = jax.jit(lambda p, c, t: mdl.decode_step(cfg, p, c, t,
+                                                         impl="xla"))
+    rng = np.random.default_rng(seed)
+    shape = (cfg.num_layers, cfg.num_kv_heads)
+    dt = jnp.dtype(cfg.dtype)
+    reps = 20
+
+    def paged(impl, table, step, slot):
+        """Logits of the slot, and seconds per step over `reps` steps
+        queued back to back (each rewrites the same position in place)."""
+        pk, pv = jnp.copy(eng.kvpool.k), jnp.copy(eng.kvpool.v)
+        logits, pk, pv = engine_mod._decode(params, pk, pv, table, step,
+                                            cfg=cfg, impl=impl)
+        jax.block_until_ready(logits)          # compiled, warmed
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            logits, pk, pv = engine_mod._decode(params, pk, pv, table, step,
+                                                cfg=cfg, impl=impl)
+        jax.block_until_ready(logits)
+        dt_s = (time.perf_counter() - t0) / reps
+        return np.asarray(logits[slot], np.float32), dt_s
+
+    for n in size.decode_lens:
+        k = jnp.asarray(rng.normal(size=shape + (n, cfg.head_dim)), dt)
+        v = jnp.asarray(rng.normal(size=shape + (n, cfg.head_dim)), dt)
+        eng.scatter_kv(n, k, v)
+        slot = eng.bind_slot(n)
+        tok = int(rng.integers(0, cfg.vocab_size))
+        step = np.zeros((3, slots), np.int32)
+        step[0, slot], step[1], step[2, slot] = tok, eng.slot_lengths(), 1
+        table, step = eng._page_table(), jnp.asarray(step)
+        got, t_kernel = paged(kernel, table, step, slot)
+        xla, t_xla = paged("xla", table, step, slot)
+
+        dshape = (cfg.num_layers, slots, cfg.num_kv_heads, max_len,
+                  cfg.head_dim)
+        cache = {"len": jnp.asarray(eng.slot_lengths(), jnp.int32),
+                 "k": jnp.zeros(dshape, dt).at[:, slot, :, :n].set(k),
+                 "v": jnp.zeros(dshape, dt).at[:, slot, :, :n].set(v)}
+        toks = step[0]
+        jax.block_until_ready(dense_step(params, cache, toks))
+        t0 = time.perf_counter()
+        for _ in range(reps // 4):
+            logits, _ = dense_step(params, cache, toks)
+            jax.block_until_ready(logits)
+        t_dense = (time.perf_counter() - t0) / (reps // 4)
+        dense = np.asarray(logits[slot], np.float32)
+        del cache
+        eng.evict(slot)
+
+        (xrel,), (xmx,) = logit_errors(got[None], xla[None])
+        (drel,), (dmx,) = logit_errors(got[None], dense[None])
+        print(f"decode program, one live slot of {n} tokens in {slots} x "
+              f"{max_len}: {kernel} {1e3 * t_kernel:.3f} ms/step, XLA paged "
+              f"{1e3 * t_xla:.3f} ms/step, dense decode_step "
+              f"{1e3 * t_dense:.3f} ms/step; {kernel} logits vs XLA paged "
+              f"relative L2 {xrel:.6f} largest {xmx:.6f} std, vs dense "
+              f"{drel:.6f} largest {dmx:.6f} std")
+        check(np.isfinite(got).all() and xrel <= LOGIT_REL_TOL
+              and xmx <= LOGIT_MAX_TOL and drel <= LOGIT_REL_TOL
+              and dmx <= LOGIT_MAX_TOL,
+              f"{kernel} decode logits match the XLA paged path and the "
+              f"dense decode_step at {n} tokens")
+
+
 def one_chip(size: Size, seed: int) -> None:
     compiles = CompileCounter()
     cfg = size.cfg
@@ -310,6 +401,8 @@ def one_chip(size: Size, seed: int) -> None:
     gc.collect()
     reference_phase(size, params, short, got, canary)
     print(f"peak_bytes_in_use: {peak_bytes()}")
+    gc.collect()
+    paged_decode_phase(size, params, seed)
 
 
 # ---------------------------------------------------------------------------

@@ -107,3 +107,130 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
         interpret=interpret,
     )(cache_len.astype(jnp.int32), q[:, :, None], kp, vp)
     return out[:, :, 0]
+
+
+# ---------------------------------------------------------------------------
+# Paged: the same step over a paged KV pool, read in place through a table
+# ---------------------------------------------------------------------------
+#: pages one grid step copies in and attends over; a table's width is a
+#: multiple of it (the engine pads its page tables to one)
+PAGES_PER_STEP = 16
+
+
+def _paged_decode_kernel(lens_ref, pages_ref,            # SMEM (B,), (B*P,)
+                         q_ref, k_hbm, v_hbm,             # q VMEM; pool HBM
+                         o_ref,
+                         k_buf, v_buf, sems, m_ref, l_ref, acc_ref,
+                         *, pages_per_step: int, pages_per_seq: int):
+    b = pl.program_id(0)
+    length = lens_ref[b]
+    n_kv, page = k_hbm.shape[1], k_hbm.shape[2]
+    d = k_hbm.shape[3]
+    bk = pages_per_step * page
+
+    def copies(i, buf):
+        """The DMAs of step i's pages, every KV head of a page in one."""
+        out = []
+        for j in range(pages_per_step):
+            p = pages_ref[b * pages_per_seq + i * pages_per_step + j]
+            out.append(pltpu.make_async_copy(k_hbm.at[p], k_buf.at[buf, j],
+                                             sems.at[0, buf]))
+            out.append(pltpu.make_async_copy(v_hbm.at[p], v_buf.at[buf, j],
+                                             sems.at[1, buf]))
+        return out
+
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    n_steps = (length + bk - 1) // bk
+
+    @pl.when(n_steps > 0)
+    def _first():
+        for c in copies(0, 0):
+            c.start()
+
+    def step(i, carry):
+        buf = i % 2
+
+        @pl.when(i + 1 < n_steps)
+        def _next():                    # the next step's pages, meanwhile
+            for c in copies(i + 1, 1 - buf):
+                c.start()
+
+        for c in copies(i, buf):
+            c.wait()
+        valid = i * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1) \
+            < length
+        for h in range(n_kv):
+            k = k_buf[buf, :, h].reshape(bk, d)
+            v = v_buf[buf, :, h].reshape(bk, d)
+            q = q_ref[0, h].astype(k.dtype)                      # (G, D)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(valid, s * d ** -0.5, NEG_INF)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + p.sum(axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_steps, step, 0)
+    l = l_ref[...]
+    o_ref[0] = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+
+
+@functools.partial(jax.jit, static_argnames=("pages_per_step", "interpret"))
+def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
+                       pages: jax.Array, lengths: jax.Array, *,
+                       pages_per_step: int = PAGES_PER_STEP,
+                       interpret: bool = False) -> jax.Array:
+    """q (B, H, D); k_pages, v_pages (N, KV, page, D), the whole pool;
+    pages (B, P) int32, row b the pool pages of sequence b in order;
+    lengths (B,): sequence b attends over its first lengths[b] tokens, and
+    0 gives zeros.  Returns (B, H, D).
+
+    One grid step per sequence walks its pages `pages_per_step` at a time,
+    double-buffered: only pages below the length are read, each page by
+    one DMA of every KV head.  Scores and softmax are float32; both
+    matmuls take the pool's dtype with float32 accumulation."""
+    b, h, d = q.shape
+    n_kv, page = k_pages.shape[1], k_pages.shape[2]
+    n_pages = pages.shape[1]
+    pps = min(pages_per_step, n_pages)
+    if n_pages % pps:
+        raise ValueError(f"page table width {n_pages} is not a multiple of "
+                         f"{pps} pages per step")
+    g = h // n_kv
+    qg = q.astype(jnp.float32).reshape(b, n_kv, g, d)
+    kernel = functools.partial(_paged_decode_kernel, pages_per_step=pps,
+                               pages_per_seq=n_pages)
+    spec = pl.BlockSpec((1, n_kv, g, d), lambda ib, *_: (ib, 0, 0, 0))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[spec, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=spec,
+            scratch_shapes=[
+                pltpu.VMEM((2, pps, n_kv, page, d), k_pages.dtype),
+                pltpu.VMEM((2, pps, n_kv, page, d), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((n_kv, g, 1), jnp.float32),
+                pltpu.VMEM((n_kv, g, 1), jnp.float32),
+                pltpu.VMEM((n_kv, g, d), jnp.float32),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        out_shape=jax.ShapeDtypeStruct((b, n_kv, g, d), jnp.float32),
+        interpret=pltpu.InterpretParams() if interpret else False,
+    )(lengths.astype(jnp.int32), pages.reshape(-1).astype(jnp.int32), qg,
+      k_pages, v_pages)
+    return out.reshape(b, h, d).astype(q.dtype)

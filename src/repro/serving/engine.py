@@ -10,10 +10,11 @@ replica's `PagedKVCache` (serving/kvcache.py), whether it arrived through
 `admit` (a finished local prefill, §5.2 migration), `scatter_kv` (a gang-SP
 prefill scattering its sharded KV back to the home replica) or grows token
 by token during decode.  Decode slots are thin identities over the pool: a
-slot binds a rid into the batched decode step; the dense (L, slots, KV,
-S_max, hd) view the jitted decode kernel consumes is gathered from the pool
-per iteration, and the new token's KV is appended back block-granularly —
-one KV path for gang scatter, preemption eviction and decode alike.
+slot binds a rid into the batched decode step, whose program takes the
+pool (donated) and a page table of the bound slots' blocks, writes each
+active slot's new KV into its own block and attends over the pool in
+place, up to each slot's live length — one KV path for gang scatter,
+preemption eviction and decode alike.
 
 The engine targets the dense family (the paper's evaluation models are all
 dense); decode runs slot-batched with per-slot cache lengths — continuous
@@ -31,7 +32,9 @@ import numpy as np
 
 from repro import obs
 from repro.configs.base import ModelConfig
+from repro.kernels import flash_decode as fd
 from repro.kernels import ops
+from repro.models import layers as L
 from repro.models import model as mdl
 from repro.serving.kvcache import PagedKVCache, PrefixHit
 
@@ -121,11 +124,80 @@ def _finalize(params, x, *, cfg: ModelConfig):
     return mdl.lm_logits(cfg, params, x[:, -1])
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _decode(params, cache_k, cache_v, slot_len, tokens, *, cfg: ModelConfig):
-    cache = {"len": slot_len, "k": cache_k, "v": cache_v}
-    logits, cache = mdl.decode_step(cfg, params, cache, tokens, impl="xla")
-    return logits, cache["k"], cache["v"]
+def _decode(params, pool_k, pool_v, table, step, *, cfg: ModelConfig,
+            impl: Optional[str] = None):
+    """One decode step on the paged pool.  pool_k/pool_v (L, N, KV, page,
+    hd), returned updated; table (slots, P) int32, each slot's blocks in
+    order; step (3, slots) int32, each slot's token, length and whether it
+    is active.  Per layer: each active slot's new K/V goes into its own
+    block at `length % page` (an inactive slot's into the pool's last
+    block, which no table owns), then attention runs over the slot's
+    [0, length + 1) through the table, as `models.model.decode_step` runs
+    it over a dense cache.  `impl` defaults to what
+    `ops.paged_decode_impl` picks here.
+
+    On an accelerator the pool is donated and updated in place: a copy of
+    it is what a step must not make.  On the host the caller's arrays stay
+    valid, so a stand-in that hands its input pool back still runs (the
+    benchmark's fault test of a step that leaves its state unchanged)."""
+    impl = impl or ops.paged_decode_impl(cfg.head_dim, cfg.sliding_window)
+    program = _decode_program(jax.default_backend() != "cpu")
+    return program(params, pool_k, pool_v, table, step, cfg=cfg, impl=impl)
+
+
+@functools.lru_cache(maxsize=None)
+def _decode_program(donate: bool):
+    """`_decode`'s program, compiled under that name; `donate` hands it the
+    pool to update in place."""
+    return jax.jit(_decode_step, static_argnames=("cfg", "impl"),
+                   donate_argnums=(1, 2) if donate else ())
+
+
+def _decode_step(params, pool_k, pool_v, table, step, *, cfg, impl):
+    tok, lens, active = step[0], step[1], step[2] > 0
+    nl, nb, kvh, page, hd = pool_k.shape
+    B, H = tok.shape[0], cfg.num_heads
+    # the layer folds into the page axis: layer i's block b is page
+    # i * nb + b of one (L * N, KV, page, hd) array the kernel gets whole
+    pk = pool_k.reshape(nl * nb, kvh, page, hd)
+    pv = pool_v.reshape(nl * nb, kvh, page, hd)
+    own = jnp.take_along_axis(table, (lens // page)[:, None], 1)[:, 0]
+    dest = jnp.where(active, own, nb - 1)
+    off = jnp.where(active, lens % page, 0)
+    kv_len = jnp.where(active, lens + 1, 0)
+    x = params["embed"][tok].astype(jnp.dtype(cfg.dtype))     # (B, d)
+
+    def body(carry, inp):
+        x, pk, pv = carry
+        i, pl = inp
+        a = pl["attn"]
+        h = L.rms_norm(x, pl["ln1"], cfg.norm_eps)
+        q = L.linear(h, a["wq"], a.get("bq")).reshape(B, H, hd)
+        k = L.linear(h, a["wk"], a.get("bk")).reshape(B, kvh, hd)
+        v = L.linear(h, a["wv"], a.get("bv")).reshape(B, kvh, hd)
+        q = L.rope(q[:, None], lens[:, None], cfg.rope_theta)[:, 0]
+        k = L.rope(k[:, None], lens[:, None], cfg.rope_theta)[:, 0]
+        for s in range(B):
+            at = (i * nb + dest[s], 0, off[s], 0)
+            pk = jax.lax.dynamic_update_slice(
+                pk, k[s][None, :, None].astype(pk.dtype), at)
+            pv = jax.lax.dynamic_update_slice(
+                pv, v[s][None, :, None].astype(pv.dtype), at)
+        o = ops.paged_decode_attention(q, pk, pv, table + i * nb, kv_len,
+                                       sliding_window=cfg.sliding_window,
+                                       impl=impl)
+        x = x + L.linear(o.reshape(B, H * hd), a["wo"])
+        x = x + L.swiglu(L.rms_norm(x, pl["ln2"], cfg.norm_eps), pl["mlp"])
+        return (x, pk, pv), None
+
+    (x, pk, pv), _ = jax.lax.scan(body, (x, pk, pv),
+                                  (jnp.arange(nl), params["layers"]))
+    return (mdl.lm_logits(cfg, params, x), pk.reshape(pool_k.shape),
+            pv.reshape(pool_v.shape))
+
+
+# the device trace and the benchmark's readers find the program by this name
+_decode_step.__name__ = _decode_step.__qualname__ = "_decode"
 
 
 class ReplicaEngine:
@@ -154,12 +226,19 @@ class ReplicaEngine:
         # headroom for a slotless gang-scattered resident awaiting its
         # decode slot; a smaller explicit n_blocks makes the block budget
         # the binding constraint.
-        self.kvpool = PagedKVCache.create(
-            nl, n_blocks if n_blocks is not None
-            else (max_slots + 1) * self.blocks_per_seq, KV, block_size,
-            hd, dt)
+        n_blocks = (n_blocks if n_blocks is not None
+                    else (max_slots + 1) * self.blocks_per_seq)
+        # one block more than the budget, the last, which no table owns:
+        # decode writes of inactive slots and page-table padding point at it
+        self.kvpool = PagedKVCache.create(nl, n_blocks + 1, KV, block_size,
+                                          hd, dt)
+        self.scratch = self.kvpool.free.pop()
+        #: decode page table width: the kernel walks it a whole number of
+        #: steps at a time
+        self.pages_per_seq = -(-self.blocks_per_seq // fd.PAGES_PER_STEP) \
+            * fd.PAGES_PER_STEP
         self.slot_rid: List[Optional[int]] = [None] * max_slots
-        self._view = None                      # cached dense decode view
+        self._table = None           # device page table, rebuilt on rebinding
 
     # ---- prefill (preemptible) ---------------------------------------------
     def start_prefill(self, rid: int, tokens: jnp.ndarray,
@@ -223,21 +302,17 @@ class ReplicaEngine:
 
     def release_kv(self, rid: int) -> None:
         """Drop a resident request's blocks (preemption eviction / cleanup).
-
-        Invalidates the cached dense decode view: releasing a rid that is
-        (or was) slot-visible would otherwise leave its stale KV in the
-        cached view until the next admit/bind — the next decode iteration
-        must see the pool without the released blocks."""
+        The decode page table is rebuilt, so no slot's row keeps them."""
         if rid in self.kvpool.tables:
             self.kvpool.release(rid)
-            self._invalidate_view()
+            self._table = None
 
     def clear(self) -> None:
         """Evict every slot, release every resident request AND forget the
         prefix cache — a cleared engine is bit-identical to a fresh one
         (cross-run determinism for the policy-comparison harnesses)."""
         self.slot_rid = [None] * self.max_slots
-        self._invalidate_view()
+        self._table = None
         for rid in list(self.kvpool.tables):
             self.kvpool.release(rid)
         self.kvpool.drop_cache()
@@ -296,7 +371,7 @@ class ReplicaEngine:
             raise SlotsFull(str(e)) from e
         slot = free[0]
         self.slot_rid[slot] = rid
-        self._invalidate_view()
+        self._table = None
         return slot
 
     def admit(self, rid: int, st: PrefillState) -> int:
@@ -335,80 +410,61 @@ class ReplicaEngine:
             obs.count("admit/tokens", S)
             slot = free[0]
             self.slot_rid[slot] = rid
-            self._invalidate_view()
+            self._table = None
         return slot
 
     def evict(self, slot: int) -> None:
         rid = self.slot_rid[slot]
         self.slot_rid[slot] = None
         if rid is not None:
-            self.release_kv(rid)    # invalidates the cached dense view
+            self.release_kv(rid)
 
     def slot_lengths(self) -> List[int]:
         return [self.kvpool.lengths.get(rid, 0) if rid is not None else 0
                 for rid in self.slot_rid]
 
     # ---- decode -------------------------------------------------------------
-    def _invalidate_view(self) -> None:
-        self._view = None
-
-    def _dense_view(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """The slot-batched dense cache the jitted decode step consumes,
-        gathered from the pool.  Cached between iterations: decode itself
-        is the only writer while slot bindings are stable (the returned
-        updated cache from `_decode` already carries the appended tokens),
-        so a full rebuild happens only after admit/bind/evict/clear —
-        per-token cost stays proportional to the step, not the pool."""
-        if self._view is not None:
-            return self._view
-        with obs.span("decode_iteration/view"):
-            cfg = self.cfg
-            nl, KV, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-            dt = jnp.dtype(cfg.dtype)
-            ck = jnp.zeros((nl, self.max_slots, KV, self.max_len, hd), dt)
-            cv = jnp.zeros((nl, self.max_slots, KV, self.max_len, hd), dt)
+    def _page_table(self) -> jax.Array:
+        """(max_slots, pages_per_seq) int32: each bound slot's blocks, the
+        rest of its row and every unbound row the scratch block."""
+        if self._table is None:
+            t = np.full((self.max_slots, self.pages_per_seq), self.scratch,
+                        np.int32)
             for s, rid in enumerate(self.slot_rid):
-                if rid is None or not self.resident(rid):
-                    continue
-                k, v = self.kvpool.gather(rid)
-                pad = self.max_len - k.shape[2]
-                k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
-                v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
-                ck = ck.at[:, s].set(k)
-                cv = cv.at[:, s].set(v)
-            self._view = (ck, cv)
-        return self._view
+                blocks = self.kvpool.tables.get(rid, ())
+                t[s, :len(blocks)] = blocks
+            self._table = jnp.asarray(t)
+        return self._table
 
     def decode_logits(self, tokens: Dict[int, int]) -> jax.Array:
         """One continuous-batching iteration over the active slots.
-        tokens: slot -> last token id.  Appends each active slot's new KV
-        to the pool and returns the (max_slots, V) logits."""
+        tokens: slot -> last token id.  Writes each active slot's new KV
+        into the pool and returns the (max_slots, V) logits."""
         with obs.span("decode_iteration/prep"):
-            tok = np.zeros((self.max_slots,), np.int32)
-            for s, t in tokens.items():
-                tok[s] = t
-            cache_k, cache_v = self._dense_view()
             lens = self.slot_lengths()
-            slot_len, tok = jnp.asarray(lens, jnp.int32), jnp.asarray(tok)
-        with obs.span("decode_iteration/program"):
-            logits, new_k, new_v = _decode(self.params, cache_k, cache_v,
-                                           slot_len, tok, cfg=self.cfg)
-        obs.count("decode_iteration/slots", len(tokens))
-        # the updated dense cache carries the appended tokens (inactive
-        # slots' writes land at masked positions, same as the pre-paged
-        # engine) — keep it as the live view
-        self._view = (new_k, new_v)
-        # append the new token's KV back to the pool — active slots only.
-        # Slots reserved their full budget at admission, so this never
-        # allocates and cannot fail mid-iteration.
-        with obs.span("decode_iteration/append"):
-            for s in tokens:
+            step = np.zeros((3, self.max_slots), np.int32)
+            step[1] = lens
+            for s, t in tokens.items():
                 rid = self.slot_rid[s]
-                pos = lens[s]
-                if pos >= self.max_len:
+                if rid is None:
+                    raise ValueError(f"decode on unbound slot {s}")
+                if lens[s] >= self.max_len:
                     raise ValueError("decode past engine max_len")
-                self.kvpool.append_token(rid, new_k[:, s, :, pos],
-                                         new_v[:, s, :, pos])
+                # slots reserved their full budget at admission, so this
+                # only forks a block another table shares
+                if self.kvpool.own_block(rid, lens[s]):
+                    self._table = None
+                step[0, s], step[2, s] = t, 1
+            table = self._page_table()
+            step = jnp.asarray(step)
+        with obs.span("decode_iteration/program"):
+            logits, self.kvpool.k, self.kvpool.v = _decode(
+                self.params, self.kvpool.k, self.kvpool.v, table, step,
+                cfg=self.cfg)
+        for s in tokens:
+            self.kvpool.lengths[self.slot_rid[s]] += 1
+        obs.count("decode_iteration/slots", len(tokens))
+        obs.count("decode_iteration/kv_tokens", sum(lens[s] for s in tokens))
         return logits
 
     def decode_iteration(self, tokens: Dict[int, int]) -> Dict[int, int]:

@@ -1,7 +1,9 @@
 """Paged KV cache (PagedAttention-style, the mechanism of the paper's vLLM
 substrate): a fixed pool of fixed-size blocks + per-request block tables.
-Non-contiguous physical storage eliminates fragmentation; gather by block
-table materializes the contiguous view the attention kernels consume.
+Non-contiguous physical storage eliminates fragmentation.  Decode reads and
+writes the pool in place through the tables (`serving/engine.py`); gather
+by block table materializes contiguous KV where a caller needs it (a
+prefix hit's suffix prefill, parking KV off the device).
 
 Prefix reuse (vLLM-v1-style): full blocks are indexed by a chain hash over
 their token content (h_i = hash((h_{i-1}, block_tokens))), so an admit whose
@@ -11,14 +13,15 @@ blocks' prefill compute entirely.  Blocks whose refcount drops to zero but
 that still carry a registered hash are parked on a `cached` free list (data
 retained, LRU-evicted only when a plain allocation needs room), so a prefix
 survives between requests — the property cross-turn chat reuse depends on.
-Tables are copy-on-write: `append_token` into a block another table still
-references forks a private copy first.
+Tables are copy-on-write: a write into a block another table still
+references forks a private copy first (`own_block`).
 
 Pure JAX: the pool is a pytree; allocation metadata is host-side (block
 tables are tiny and scheduler-owned, exactly as in vLLM).
 """
 from __future__ import annotations
 
+import functools
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -32,6 +35,21 @@ def _chain_hash(parent: Optional[int], tokens: Tuple[int, ...]) -> int:
     Collisions are assumed absent (the standard vLLM trade; a collision
     would silently alias two prefixes, acceptable for a simulator/repro)."""
     return hash((parent, tokens))
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _write_blocks(pool_k, pool_v, idx, k, v):
+    """Write k/v (L, KV, S, hd), padded to whole blocks, into the pool's
+    blocks `idx` in place (the pool is donated)."""
+    L, KV, S, hd = k.shape
+    n, bs = idx.shape[0], pool_k.shape[3]
+    pad = ((0, 0), (0, 0), (0, n * bs - S), (0, 0))
+
+    def blocks(x):
+        return jnp.pad(x, pad).reshape(L, KV, n, bs, hd).transpose(0, 2, 1, 3,
+                                                                    4)
+    return (pool_k.at[:, idx].set(blocks(k).astype(pool_k.dtype)),
+            pool_v.at[:, idx].set(blocks(v).astype(pool_v.dtype)))
 
 
 @dataclass
@@ -205,7 +223,7 @@ class PagedKVCache:
         tokens is None)."""
         if rid in self.tables:
             raise KeyError(f"rid {rid} already resident")
-        L, KV, S, hd = k.shape
+        S = k.shape[2]
         bs = self.block_size
         need = self.blocks_needed(S)
         hit = PrefixHit()
@@ -237,15 +255,9 @@ class PagedKVCache:
             raise
         if new_blocks:
             lo = n_shared * bs
-            pad = need * bs - S
-            ks = jnp.pad(k[:, :, lo:], ((0, 0), (0, 0), (0, pad), (0, 0)))
-            vs = jnp.pad(v[:, :, lo:], ((0, 0), (0, 0), (0, pad), (0, 0)))
-            n_new = len(new_blocks)
-            kb = ks.reshape(L, KV, n_new, bs, hd)
-            vb = vs.reshape(L, KV, n_new, bs, hd)
-            idx = jnp.asarray(new_blocks)
-            self.k = self.k.at[:, idx].set(kb.transpose(0, 2, 1, 3, 4))
-            self.v = self.v.at[:, idx].set(vb.transpose(0, 2, 1, 3, 4))
+            self.k, self.v = _write_blocks(self.k, self.v,
+                                           jnp.asarray(new_blocks, jnp.int32),
+                                           k[:, :, lo:], v[:, :, lo:])
             for b in new_blocks:
                 self.ref[b] = 1
         table = shared + new_blocks
@@ -279,33 +291,42 @@ class PagedKVCache:
                 if len(self.tails[h]) > 1:
                     self.stats["cow_forks"] += 1
 
-    def append_token(self, rid: int, k: jax.Array, v: jax.Array) -> None:
-        """Append one token's KV. k/v: (L, KV, hd).  Copy-on-write: if the
-        target block is shared with another table, fork a private copy
-        first so the sharer's bytes are never disturbed."""
-        pos = self.lengths[rid]
+    def own_block(self, rid: int, pos: int) -> bool:
+        """Make the block that takes token `pos` of `rid` its own, so a
+        write there disturbs no other table: grow the table by a block at
+        its end, or fork a block another table shares (copy-on-write).
+        Returns whether the table changed."""
         blocks = self.tables[rid]
-        if pos >= len(blocks) * self.block_size:
+        bi = pos // self.block_size
+        if bi >= len(blocks):
             blocks.append(self._alloc(1)[0])
             self.ref[blocks[-1]] = 1
-        bi = pos // self.block_size
+            return True
         b = blocks[bi]
-        if self.ref.get(b, 0) > 1:
-            nb = self._alloc(1)[0]
-            self.k = self.k.at[:, nb].set(self.k[:, b])
-            self.v = self.v.at[:, nb].set(self.v[:, b])
-            self.ref[b] -= 1
-            self.ref[nb] = 1
-            blocks[bi] = nb
-            b = nb
-            self.stats["cow_forks"] += 1
+        if self.ref.get(b, 0) <= 1:
+            return False
+        nb = self._alloc(1)[0]
+        self.k = self.k.at[:, nb].set(self.k[:, b])
+        self.v = self.v.at[:, nb].set(self.v[:, b])
+        self.ref[b] -= 1
+        self.ref[nb] = 1
+        blocks[bi] = nb
+        self.stats["cow_forks"] += 1
+        return True
+
+    def append_token(self, rid: int, k: jax.Array, v: jax.Array) -> None:
+        """Append one token's KV. k/v: (L, KV, hd), into a block of the
+        request's own (`own_block`)."""
+        pos = self.lengths[rid]
+        self.own_block(rid, pos)
+        b = self.tables[rid][pos // self.block_size]
         off = pos % self.block_size
         self.k = self.k.at[:, b, :, off].set(k)
         self.v = self.v.at[:, b, :, off].set(v)
         self.lengths[rid] = pos + 1
 
     def gather(self, rid: int):
-        """Contiguous (L, KV, S, hd) view for the attention kernels."""
+        """A request's KV, contiguous: (L, KV, S, hd)."""
         blocks = jnp.asarray(self.tables[rid])
         S = self.lengths[rid]
         k = self.k[:, blocks]          # (L, n, KV, bs, hd)
@@ -318,8 +339,8 @@ class PagedKVCache:
     def reserve(self, rid: int, capacity_tokens: int) -> None:
         """Grow a resident request's block table to hold `capacity_tokens`
         WITHOUT writing data — allocated-but-unused growth room.  Decode
-        slots reserve their sequence's full budget up front so
-        `append_token` never has to allocate (and so admission, where
+        slots reserve their sequence's full budget up front so a
+        decode write never has to allocate (and so admission, where
         callers know how to wait, is the only place that can run out of
         blocks)."""
         blocks = self.tables[rid]

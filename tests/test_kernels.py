@@ -8,7 +8,7 @@ import pytest
 
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.flash_decode import flash_decode
+from repro.kernels.flash_decode import flash_decode, paged_flash_decode
 from repro.kernels.ssd_kernel import ssd_scan_pallas
 
 RNG = np.random.default_rng(42)
@@ -87,6 +87,47 @@ def test_flash_decode_matches_reference(win, bk):
     got = flash_decode(q, k, v, cl, sliding_window=win, block_k=bk,
                        interpret=True)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+PAGED_CASES = [
+    # h, kv, page, pages per sequence, pages per step, lengths, window
+    (8, 2, 4, 8, 4, (5, 0, 31, 16), 0),        # 4 q heads per KV head
+    (14, 2, 8, 4, 2, (1, 32, 17), 0),          # 7
+    (4, 4, 4, 6, 6, (24, 3), 0),               # MHA, one step per sequence
+    (8, 2, 4, 8, 4, (5, 30), 6),               # window: the XLA path only
+]
+
+
+@pytest.mark.parametrize("case,impl", [
+    (c, impl) for c in PAGED_CASES for impl in ("xla", "pallas_interpret")
+    if impl == "xla" or not c[-1]])
+def test_paged_decode_matches_reference_on_gathered_pages(case, impl):
+    """Attention through a page table into a shared pool == the reference
+    over each sequence's pages gathered in order; a length of 0 gives
+    zeros from the kernel."""
+    h, kv, page, n, per_step, lens, win = case
+    d, n_pool = 16, 40
+    b = len(lens)
+    kp, vp = t(n_pool, kv, page, d), t(n_pool, kv, page, d)
+    pages = jnp.asarray(np.stack([RNG.permutation(n_pool)[:n]
+                                  for _ in range(b)]), jnp.int32)
+    q = t(b, h, d)
+    cl = jnp.asarray(lens, jnp.int32)
+    k = kp[pages].transpose(0, 2, 1, 3, 4).reshape(b, kv, n * page, d)
+    v = vp[pages].transpose(0, 2, 1, 3, 4).reshape(b, kv, n * page, d)
+    want = np.asarray(ref.decode_attention_reference(q, k, v, cl,
+                                                     sliding_window=win))
+    if impl == "xla":
+        got = ops.paged_decode_attention(q, kp, vp, pages, cl,
+                                         sliding_window=win, impl="xla")
+    else:
+        got = paged_flash_decode(q, kp, vp, pages, cl,
+                                 pages_per_step=per_step, interpret=True)
+    got = np.asarray(got)
+    live = np.asarray(lens) > 0
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5, rtol=2e-5)
+    if impl != "xla":
+        assert not got[~live].any()
 
 
 @pytest.mark.parametrize("chunk,seq", [(16, 64), (32, 96), (32, 70), (8, 8)])

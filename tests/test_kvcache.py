@@ -74,10 +74,10 @@ def _prefill(eng, rid, toks):
 
 def test_release_kv_invalidates_cached_decode_view():
     """Regression: `release_kv` (the slotless cleanup path — gang parks,
-    lane retirement outside `evict`) must drop the cached dense decode
-    view.  Before the fix only `evict` invalidated, so a release left the
-    freed request's KV resident in the cached view: the next decode
-    iteration read stale cache instead of the pool's truth."""
+    lane retirement outside `evict`) must take the released blocks out of
+    what the next decode step reads.  Once a cached dense view kept them;
+    now the step's page table must not point at them, and B's continuation
+    must be bit-identical to an engine that retired A through `evict`."""
     cfg = dataclasses.replace(
         reduced_config(get_config("mistral_7b"), layers=2),
         dtype="float32", sliding_window=0)
@@ -86,17 +86,20 @@ def test_release_kv_invalidates_cached_decode_view():
     eng = ReplicaEngine(cfg, params, max_slots=2, max_len=64)
     sA = eng.admit(0, _prefill(eng, 0, jnp.arange(1, 9)[None]))
     sB = eng.admit(1, _prefill(eng, 1, jnp.arange(11, 23)[None]))
-    out = eng.decode_iteration({sA: 1, sB: 2})   # caches the dense view
-    assert eng._view is not None
+    out = eng.decode_iteration({sA: 1, sB: 2})
+    blocks_a = set(eng.kvpool.tables[0])
+    assert blocks_a <= set(np.asarray(eng._page_table()[sA]).tolist())
 
     eng.slot_rid[sA] = None       # lane retired without going through evict
     eng.release_kv(0)             # ...cleanup releases the blocks directly
-    assert eng._view is None, "release_kv left the cached view stale"
-    ck, cv = eng._dense_view()
-    assert not jnp.any(ck[:, sA]) and not jnp.any(cv[:, sA])
+    table = np.asarray(eng._page_table())
+    assert not blocks_a & set(table[sA].tolist()), \
+        "release_kv left the released blocks in the decode page table"
+    assert not blocks_a & set(table[sB].tolist())
+    assert (table[sA] == eng.scratch).all()
 
     # B's continuation is bit-identical to an engine that retired A through
-    # the normal evict path (the view rebuild changed nothing for B)
+    # the normal evict path
     ref = ReplicaEngine(cfg, params, max_slots=2, max_len=64)
     rA = ref.admit(0, _prefill(ref, 0, jnp.arange(1, 9)[None]))
     rB = ref.admit(1, _prefill(ref, 1, jnp.arange(11, 23)[None]))
@@ -104,10 +107,14 @@ def test_release_kv_invalidates_cached_decode_view():
     assert ref_out == out
     ref.evict(rA)
     for _ in range(3):
-        nxt = eng.decode_iteration({sB: out[sB]})
-        ref_nxt = ref.decode_iteration({rB: ref_out[rB]})
-        assert nxt[sB] == ref_nxt[rB]
-        out, ref_out = nxt, ref_nxt
+        lb = eng.decode_logits({sB: out[sB]})[sB]
+        ref_lb = ref.decode_logits({rB: ref_out[rB]})[rB]
+        assert jnp.array_equal(lb, ref_lb)
+        out = {sB: int(jnp.argmax(lb))}
+        ref_out = {rB: int(jnp.argmax(ref_lb))}
+    kb, _ = eng.kvpool.gather(1)
+    ref_kb, _ = ref.kvpool.gather(1)
+    assert jnp.array_equal(kb, ref_kb)
 
 
 def test_paged_equals_dense_decode_attention():

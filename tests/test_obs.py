@@ -104,15 +104,14 @@ def test_engine_spans_count_quanta_migrations_and_steps(small_model, tracer):
         "prefill_quantum/kv_split": 2,
         "admit": 1, "admit/assemble": 1, "admit/pool_write": 1,
         "decode_iteration": 3, "decode_iteration/prep": 3,
-        "decode_iteration/view": 1,         # rebuilt once, after the admit
-        "decode_iteration/program": 3, "decode_iteration/append": 3,
-        "decode_iteration/readback": 3}
+        "decode_iteration/program": 3, "decode_iteration/readback": 3}
+    # the steps read 24, 25 and 26 live tokens of KV
     assert tracer.counters == {"admit/tokens": 24,
-                               "decode_iteration/slots": 3}
+                               "decode_iteration/slots": 3,
+                               "decode_iteration/kv_tokens": 24 + 25 + 26}
     for parent in ("prefill_quantum", "admit", "decode_iteration"):
         kids = sum(a.total_s for k, a in tracer.spans.items()
-                   if k.startswith(parent + "/") and k.count("/") == 1
-                   and k != "decode_iteration/view")
+                   if k.startswith(parent + "/") and k.count("/") == 1)
         agg = tracer.spans[parent]
         assert agg.self_s == pytest.approx(agg.total_s - kids, abs=1e-9)
 

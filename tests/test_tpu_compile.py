@@ -113,14 +113,44 @@ def test_engine_prefill_quantum_compiles_for_v5e(one_chip, params_shape):
     _fits_one_chip(compiled, 4.1e9 + x.size * 2)
 
 
+def _decode_args(params_shape, sharding, max_len):
+    """The engine's decode program's arguments at `max_len` tokens a slot:
+    weights, K and V pools of (slots + 1) sequences and the scratch
+    block, page table, step vector."""
+    eng_shape = jax.eval_shape(
+        lambda: engine.ReplicaEngine(CFG, None, max_slots=SLOTS,
+                                     max_len=max_len).kvpool.k)
+    pool = _spec(eng_shape.shape, jnp.bfloat16, sharding)
+    pages = -(-max_len // 16)
+    pages = -(-pages // fd.PAGES_PER_STEP) * fd.PAGES_PER_STEP
+    return (_shapes(params_shape, sharding), pool, pool,
+            _spec((SLOTS, pages), jnp.int32, sharding),
+            _spec((3, SLOTS), jnp.int32, sharding))
+
+
 def test_engine_decode_step_compiles_for_v5e(one_chip, params_shape):
-    params = _shapes(params_shape, one_chip)
-    kv = _spec((CFG.num_layers, SLOTS, CFG.num_kv_heads, MAX_LEN,
-                CFG.head_dim), jnp.bfloat16, one_chip)
-    vec = _spec((SLOTS,), jnp.int32, one_chip)
-    compiled = engine._decode.lower(params, kv, kv, vec, vec,
-                                    cfg=CFG).compile()
-    _fits_one_chip(compiled, 4.1e9 + 2 * kv.size * 2 + 64)
+    args = _decode_args(params_shape, one_chip, MAX_LEN)
+    compiled = engine._decode_program(True).lower(
+        *args, cfg=CFG, impl="pallas").compile()
+    pool = args[1]
+    _fits_one_chip(compiled, 4.1e9 + 2 * pool.size * 2 + 64 * 1024)
+    assert "tpu_custom_call" in compiled.as_text()       # the paged kernel
+
+
+@pytest.mark.parametrize("max_len", [MAX_LEN, 9136])
+def test_engine_decode_updates_the_pool_in_place_on_v5e(one_chip,
+                                                        params_shape,
+                                                        max_len):
+    """The decode program writes the donated pool in place and hands it
+    whole to the kernel: its outputs alias both pools, and no temporary
+    buffer holds a copy of either (9136 tokens: the benchmark's slots)."""
+    args = _decode_args(params_shape, one_chip, max_len)
+    compiled = engine._decode_program(True).lower(
+        *args, cfg=CFG, impl="pallas").compile()
+    m = compiled.memory_analysis()
+    pool_bytes = args[1].size * 2
+    assert m.alias_size_in_bytes >= 2 * pool_bytes
+    assert m.temp_size_in_bytes < pool_bytes / 100
 
 
 def test_gang_layer_slice_compiles_on_v5e_2x2(topo, params_shape):
@@ -156,9 +186,10 @@ def _small_programs():
     params = init_params(jax.random.PRNGKey(0), cfg)
     x = jnp.zeros((1, 16, cfg.d_model), jnp.bfloat16)
     pk = jnp.zeros((2, cfg.num_kv_heads, 8, cfg.head_dim), jnp.bfloat16)
-    kv = jnp.zeros((cfg.num_layers, 2, cfg.num_kv_heads, 32, cfg.head_dim),
-                   jnp.bfloat16)
-    vec = jnp.zeros((2,), jnp.int32)
+    pool = jnp.zeros((cfg.num_layers, 5, cfg.num_kv_heads, 16,
+                      cfg.head_dim), jnp.bfloat16)
+    table = jnp.zeros((2, 2), jnp.int32)
+    step = jnp.zeros((3, 2), jnp.int32)
     toks = jnp.zeros((1, 16), jnp.int32)
     mesh = gang.make_gang_mesh(1, cfg.num_heads)
     return params, {
@@ -168,8 +199,8 @@ def _small_programs():
         "suffix_prefill": lambda: engine._prefill_slice.lower(
             params, x, 2, pk, pk, cfg=cfg, n=2),
         "finalize": lambda: engine._finalize.lower(params, x, cfg=cfg),
-        "decode": lambda: engine._decode.lower(params, kv, kv, vec, vec,
-                                               cfg=cfg),
+        "decode": lambda: engine._decode_program(True).lower(
+            params, pool, pool, table, step, cfg=cfg, impl="xla"),
         "gang_embed": lambda: gang._embed.lower(params, toks, cfg=cfg),
         "gang_slice": lambda: gang._gang_slice.lower(
             params["layers"], x, 0, cfg=cfg, mesh=mesh, n=2,
