@@ -311,7 +311,7 @@ def paged_decode_phase(size: Size, params, seed: int) -> None:
     eng = ReplicaEngine(cfg, params, max_slots=slots, max_len=max_len,
                         n_blocks=2 * -(-max_len // 16))
     if jax.default_backend() == "tpu":
-        picked = ops.paged_decode_impl(cfg.head_dim, cfg.sliding_window)
+        picked = ops.paged_decode_impl(cfg.head_dim)
         check(picked == "pallas", "the engine decodes with the Pallas "
               f"kernel on the chip ({picked})")
     dense_step = jax.jit(lambda p, c, t: mdl.decode_step(cfg, p, c, t,

@@ -8,6 +8,7 @@ from __future__ import annotations
 import dataclasses
 import importlib
 from dataclasses import dataclass
+from typing import Tuple
 
 
 @dataclass(frozen=True)
@@ -21,10 +22,14 @@ class ModelConfig:
       hybrid  — Mamba2 blocks with a periodic *shared* attention block (Zamba2)
       audio   — encoder-decoder; frame embeddings feed the encoder (frontend stub)
       vlm     — decoder-only; patch embeddings are concatenated with text embeds
+      afmoe   — windowed beside full attention (``layer_types``), leading
+                dense SwiGLU layers, then sigmoid-routed experts plus a
+                shared expert; QK-norm, a sigmoid output gate, sandwich
+                norms (models/afmoe.py)
     """
 
     name: str
-    family: str  # dense | moe | ssm | hybrid | audio | vlm
+    family: str  # dense | moe | ssm | hybrid | audio | vlm | afmoe
     num_layers: int
     d_model: int
     num_heads: int
@@ -44,6 +49,16 @@ class ModelConfig:
     # expert GEMMs 16x smaller with negligible drop probability at top-1/128
     decode_capacity_factor: float = 8.0
 
+    # --- afmoe: dense lead, sigmoid-routed experts, a chip's share ---
+    num_dense_layers: int = 0      # leading layers with a d_ff SwiGLU
+    moe_d_ff: int = 0              # routed expert width
+    shared_d_ff: int = 0           # shared expert width (0: none)
+    route_scale: float = 1.0       # routed weights, normalised, times this
+    #: routed experts this chip computes, [expert_offset, + experts_held),
+    #: of the num_experts the router scores (0: all of them)
+    experts_held: int = 0
+    expert_offset: int = 0
+
     # --- SSM (Mamba2 / SSD) ---
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -58,6 +73,11 @@ class ModelConfig:
     qkv_bias: bool = False
     sliding_window: int = 0        # 0 = full causal; >0 = SWA (enables long_500k)
     rope_theta: float = 500000.0
+    #: per layer "sliding_attention" (window, RoPE) or "full_attention"
+    #: (no window; no position encoding under afmoe); () = every layer
+    #: as `sliding_window` says
+    layer_types: Tuple[str, ...] = ()
+    embed_scale: float = 1.0       # muP: embeddings times sqrt(d_model)
 
     # --- encoder-decoder ---
     encoder_layers: int = 0        # >0 => enc-dec; num_layers = decoder layers
@@ -82,6 +102,29 @@ class ModelConfig:
         a 16-wide TP axis (standard TP practice). Logits at padded positions
         are masked to -inf in loss/sampling."""
         return -(-self.vocab_size // 256) * 256
+
+    def layer_window(self, i: int) -> int:
+        """Layer i's attention window (0: full causal)."""
+        if self.layer_types and self.layer_types[i] != "sliding_attention":
+            return 0
+        return self.sliding_window
+
+    @property
+    def window_layers(self) -> Tuple[int, ...]:
+        """Layers whose KV a paged pool keeps as a ring of the window:
+        those `layer_types` marks sliding (a uniform `sliding_window`
+        keeps the full-length table it always had)."""
+        return tuple(i for i, t in enumerate(self.layer_types)
+                     if t == "sliding_attention")
+
+    @property
+    def full_layers(self) -> Tuple[int, ...]:
+        ring = set(self.window_layers)
+        return tuple(i for i in range(self.num_layers) if i not in ring)
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
 
     @property
     def is_encdec(self) -> bool:
@@ -119,6 +162,8 @@ class ModelConfig:
             nh = self.ssm_heads
             # in_proj (x,z,B,C,dt) + conv + out_proj + A,D,dt_bias + norm
             ssm = d * (2 * di + 2 * st + nh) + self.ssm_conv * (di + 2 * st) + di * d + 3 * nh + di
+        if self.family == "afmoe":
+            return self._afmoe_params(self.held_experts)
         per_layer = 0
         if self.family == "dense" or self.family == "vlm":
             per_layer = attn + mlp
@@ -141,8 +186,23 @@ class ModelConfig:
         total += 2 * d * self.num_layers  # norms (approx)
         return total
 
+    def _afmoe_params(self, experts: float) -> int:
+        d, hd, H, KV = self.d_model, self.head_dim, self.num_heads, \
+            self.num_kv_heads
+        attn = d * hd * (2 * H + 2 * KV) + H * hd * d + 2 * hd + 4 * d
+        moe = 3 * d * (self.moe_d_ff * experts + self.shared_d_ff) \
+            + d * self.num_experts
+        n_moe = self.num_layers - self.num_dense_layers
+        return int(self.num_layers * attn
+                   + self.num_dense_layers * 3 * d * self.d_ff
+                   + n_moe * moe + 2 * self.vocab_size * d + d)
+
     def active_param_count(self) -> int:
         """Active params per token (MoE counts only routed experts)."""
+        if self.family == "afmoe":      # held experts a token reaches
+            return self._afmoe_params(
+                self.experts_per_token * self.held_experts
+                / self.num_experts)
         if self.family != "moe":
             return self.param_count()
         d, dff = self.d_model, self.d_ff

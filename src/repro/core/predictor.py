@@ -306,7 +306,8 @@ class TraceHistoryPredictor(Predictor):
         lo = int(math.floor(pos))
         hi = min(lo + 1, len(vals) - 1)
         frac = pos - lo
-        return max(vals[lo] * (1 - frac) + vals[hi] * frac, 1.0)
+        # a + (b - a) f: exactly a where b == a, so monotone in q
+        return max(vals[lo] + (vals[hi] - vals[lo]) * frac, 1.0)
 
 
 class AdversarialPredictor(Predictor):

@@ -117,22 +117,38 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
 PAGES_PER_STEP = 16
 
 
-def _paged_decode_kernel(lens_ref, pages_ref,            # SMEM (B,), (B*P,)
-                         q_ref, k_hbm, v_hbm,             # q VMEM; pool HBM
-                         o_ref,
-                         k_buf, v_buf, sems, m_ref, l_ref, acc_ref,
-                         *, pages_per_step: int, pages_per_seq: int):
+def _paged_decode_kernel(*refs, pages_per_step: int, pages_per_seq: int,
+                         windowed: bool, ring: int):
+    if windowed:
+        (lens_ref, starts_ref, pages_ref,     # SMEM (B,), (B,), (B*P,)
+         q_ref, k_hbm, v_hbm, o_ref,          # q VMEM; pool HBM
+         k_buf, v_buf, sems, m_ref, l_ref, acc_ref) = refs
+    else:
+        (lens_ref, pages_ref, q_ref, k_hbm, v_hbm, o_ref,
+         k_buf, v_buf, sems, m_ref, l_ref, acc_ref) = refs
     b = pl.program_id(0)
     length = lens_ref[b]
     n_kv, page = k_hbm.shape[1], k_hbm.shape[2]
     d = k_hbm.shape[3]
     bk = pages_per_step * page
+    if windowed:
+        start = starts_ref[b]
+        first = start // page           # the first page a step reads
+
+    def page_at(i, j):
+        """Pool page of the step's j-th page: table entries in order, or
+        from the window's first page on, round the ring."""
+        if not windowed:
+            return pages_ref[b * pages_per_seq + i * pages_per_step + j]
+        lp = first + i * pages_per_step + j
+        lp = lp % ring if ring else jnp.minimum(lp, pages_per_seq - 1)
+        return pages_ref[b * pages_per_seq + lp]
 
     def copies(i, buf):
         """The DMAs of step i's pages, every KV head of a page in one."""
         out = []
         for j in range(pages_per_step):
-            p = pages_ref[b * pages_per_seq + i * pages_per_step + j]
+            p = page_at(i, j)
             out.append(pltpu.make_async_copy(k_hbm.at[p], k_buf.at[buf, j],
                                              sems.at[0, buf]))
             out.append(pltpu.make_async_copy(v_hbm.at[p], v_buf.at[buf, j],
@@ -142,7 +158,11 @@ def _paged_decode_kernel(lens_ref, pages_ref,            # SMEM (B,), (B*P,)
     m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
-    n_steps = (length + bk - 1) // bk
+    if windowed:
+        n_pages = (length + page - 1) // page - first
+        n_steps = (n_pages + pages_per_step - 1) // pages_per_step
+    else:
+        n_steps = (length + bk - 1) // bk
 
     @pl.when(n_steps > 0)
     def _first():
@@ -159,8 +179,13 @@ def _paged_decode_kernel(lens_ref, pages_ref,            # SMEM (B,), (B*P,)
 
         for c in copies(i, buf):
             c.wait()
-        valid = i * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1) \
-            < length
+        if windowed:
+            kpos = (first + i * pages_per_step) * page \
+                + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+            valid = jnp.logical_and(kpos < length, kpos >= start)
+        else:
+            valid = i * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk),
+                                                      1) < length
         for h in range(n_kv):
             k = k_buf[buf, :, h].reshape(bk, d)
             v = v_buf[buf, :, h].reshape(bk, d)
@@ -184,9 +209,11 @@ def _paged_decode_kernel(lens_ref, pages_ref,            # SMEM (B,), (B*P,)
     o_ref[0] = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
 
 
-@functools.partial(jax.jit, static_argnames=("pages_per_step", "interpret"))
+@functools.partial(jax.jit, static_argnames=("ring", "pages_per_step",
+                                             "interpret"))
 def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                       pages: jax.Array, lengths: jax.Array, *,
+                       pages: jax.Array, lengths: jax.Array,
+                       starts: jax.Array | None = None, *, ring: int = 0,
                        pages_per_step: int = PAGES_PER_STEP,
                        interpret: bool = False) -> jax.Array:
     """q (B, H, D); k_pages, v_pages (N, KV, page, D), the whole pool;
@@ -194,10 +221,16 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     lengths (B,): sequence b attends over its first lengths[b] tokens, and
     0 gives zeros.  Returns (B, H, D).
 
+    A window: `starts` (B,) has sequence b attend over [starts[b],
+    lengths[b]) only, reading from the page that holds starts[b] on; with
+    `ring` > 0, row b holds a ring of that many pages, logical page j of
+    the sequence in entry j % ring.
+
     One grid step per sequence walks its pages `pages_per_step` at a time,
-    double-buffered: only pages below the length are read, each page by
-    one DMA of every KV head.  Scores and softmax are float32; both
-    matmuls take the pool's dtype with float32 accumulation."""
+    double-buffered: only pages below the length (and from the start's)
+    are read, each page by one DMA of every KV head.  Scores and softmax
+    are float32; both matmuls take the pool's dtype with float32
+    accumulation."""
     b, h, d = q.shape
     n_kv, page = k_pages.shape[1], k_pages.shape[2]
     n_pages = pages.shape[1]
@@ -205,15 +238,21 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     if n_pages % pps:
         raise ValueError(f"page table width {n_pages} is not a multiple of "
                          f"{pps} pages per step")
+    windowed = starts is not None
     g = h // n_kv
     qg = q.astype(jnp.float32).reshape(b, n_kv, g, d)
     kernel = functools.partial(_paged_decode_kernel, pages_per_step=pps,
-                               pages_per_seq=n_pages)
+                               pages_per_seq=n_pages, windowed=windowed,
+                               ring=ring)
     spec = pl.BlockSpec((1, n_kv, g, d), lambda ib, *_: (ib, 0, 0, 0))
+    scalars = [lengths.astype(jnp.int32)]
+    if windowed:
+        scalars.append(starts.astype(jnp.int32))
+    scalars.append(pages.reshape(-1).astype(jnp.int32))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(scalars),
             grid=(b,),
             in_specs=[spec, pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
@@ -231,6 +270,5 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             dimension_semantics=("arbitrary",)),
         out_shape=jax.ShapeDtypeStruct((b, n_kv, g, d), jnp.float32),
         interpret=pltpu.InterpretParams() if interpret else False,
-    )(lengths.astype(jnp.int32), pages.reshape(-1).astype(jnp.int32), qg,
-      k_pages, v_pages)
+    )(*scalars, qg, k_pages, v_pages)
     return out.reshape(b, h, d).astype(q.dtype)

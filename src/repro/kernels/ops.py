@@ -164,34 +164,48 @@ def decode_attention(q, k, v, cache_len, *, sliding_window=0, impl="auto"):
     raise ValueError(f"unknown impl {impl}")
 
 
-def paged_decode_impl(head_dim: int, sliding_window: int) -> str:
+def paged_decode_impl(head_dim: int) -> str:
     """What `paged_decode_attention` runs here: the Pallas kernel on a TPU
-    for lane-wide heads without a window, the XLA gather elsewhere."""
-    if _on_tpu() and head_dim % 128 == 0 and not sliding_window:
+    for lane-wide heads (windows included), the XLA gather elsewhere."""
+    if _on_tpu() and head_dim % 128 == 0:
         return "pallas"
     return "xla"
 
 
 def paged_decode_attention(q, k_pages, v_pages, pages, lengths, *,
-                           sliding_window=0, impl="xla"):
+                           sliding_window=0, ring=0, impl="xla"):
     """Single new token vs a paged KV pool, read in place.  q (B,H,D);
     k/v pages (N,KV,page,D), the whole pool; pages (B,P) int32, each
-    sequence's pool pages in order; lengths (B,): tokens to attend over,
-    the newest last.  "xla" gathers each sequence's pages into a dense
-    (B,KV,P*page,D) cache and runs `decode_attention` on it (windows
-    included); the Pallas kernel reads the pages below each length in
-    place (no window)."""
+    sequence's pool pages in order — or, with `ring` > 0, a ring of that
+    many pages, logical page j in entry j % ring; lengths (B,): tokens to
+    attend over, the newest last, of which a window keeps the last
+    `sliding_window`.  "xla" gathers each sequence's pages into a dense
+    cache and runs `decode_attention` on it; the Pallas kernel reads the
+    pages below each length (and from the window's start) in place."""
+    starts = (jnp.maximum(lengths - sliding_window, 0) if sliding_window
+              else None)
     if impl in ("pallas", "pallas_interpret"):
-        assert not sliding_window, "the paged kernel has no window"
         from repro.kernels import flash_decode as fd
         return fd.paged_flash_decode(q, k_pages, v_pages, pages, lengths,
+                                     starts, ring=ring,
                                      interpret=impl == "pallas_interpret")
     if impl != "xla":
         raise ValueError(f"unknown impl {impl}")
     b, n = pages.shape
     _, kvh, page, d = k_pages.shape
+    if ring:
+        # the ring in logical order from the window's first page: entry
+        # (first + j) % ring holds positions (first + j) * page + [0, page)
+        first = starts // page
+        pages = jnp.take_along_axis(
+            pages, (first[:, None] + jnp.arange(ring)[None]) % ring, 1)
+        n = ring
     k = k_pages[pages].transpose(0, 2, 1, 3, 4).reshape(b, kvh, n * page, d)
     v = v_pages[pages].transpose(0, 2, 1, 3, 4).reshape(b, kvh, n * page, d)
+    if ring:
+        off = first * page
+        return decode_attention(q, k, v, lengths - off,
+                                sliding_window=sliding_window, impl="xla")
     return decode_attention(q, k, v, lengths, sliding_window=sliding_window,
                             impl="xla")
 

@@ -39,6 +39,9 @@ def _dtype(cfg: ModelConfig):
 # Init
 # ===========================================================================
 def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
+    if cfg.family == "afmoe":
+        from repro.models import afmoe
+        return afmoe.init_params(rng, cfg)
     dt = _dtype(cfg)
     ks = jax.random.split(rng, 8)
     d, V, nl = cfg.d_model, cfg.padded_vocab, cfg.num_layers

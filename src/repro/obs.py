@@ -16,6 +16,8 @@ No span is kept in memory one by one: the profiler's trace is the record
 of each span, the aggregates are the operator's counters.  `reset()`
 clears them (`EngineBackend.reset` does, before every run); `disable()`
 turns spans into one shared null context and counting into nothing.
+`count_device(name, x)` adds a number a program computed on the device
+without waiting for it: the counters read it when they are read.
 """
 from __future__ import annotations
 
@@ -24,6 +26,7 @@ from collections import Counter
 from contextlib import nullcontext
 from typing import Dict, List
 
+import jax
 from jax.profiler import TraceAnnotation
 
 _NULL = nullcontext()
@@ -76,8 +79,20 @@ class Tracer:
     def __init__(self):
         self.enabled = True
         self.spans: Dict[str, Agg] = {}
-        self.counters: Counter = Counter()
+        self._counters: Counter = Counter()
+        self._pending: List[tuple] = []
         self._open: List[_Span] = []
+
+    @property
+    def counters(self) -> Counter:
+        """The counters, with every device number added so far in."""
+        if self._pending:
+            names = [n for n, _ in self._pending]
+            values = jax.device_get([x for _, x in self._pending])
+            self._pending = []
+            for n, x in zip(names, values):
+                self._counters[n] += x.item()
+        return self._counters
 
     def span(self, name: str, **args):
         if not self.enabled:
@@ -86,11 +101,18 @@ class Tracer:
 
     def count(self, name: str, n: float = 1) -> None:
         if self.enabled:
-            self.counters[name] += n
+            self._counters[name] += n
+
+    def count_device(self, name: str, x: jax.Array) -> None:
+        """Add the device scalar `x` to counter `name` when the counters
+        are next read, with no wait now."""
+        if self.enabled:
+            self._pending.append((name, x))
 
     def reset(self) -> None:
         self.spans = {}
-        self.counters = Counter()
+        self._counters = Counter()
+        self._pending = []
 
     def disable(self) -> None:
         self.enabled = False
@@ -106,5 +128,6 @@ class Tracer:
 TRACER = Tracer()
 span = TRACER.span
 count = TRACER.count
+count_device = TRACER.count_device
 reset = TRACER.reset
 disable = TRACER.disable

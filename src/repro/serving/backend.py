@@ -32,7 +32,9 @@ preemption window.  Per-degree measured per-layer timings accumulate in
 `calibrate_costmodel`, so SimBackend and EngineBackend predict the same
 winner.  On hosts with fewer devices than the gang (tier-1 CI sees ONE),
 `gang_degree` collapses to 1 and the long runs the single-replica path —
-``sp_mode="ring"`` (the /FSP ablation and all baselines) always does.
+``sp_mode="ring"`` (the /FSP ablation and all baselines) always does, and
+so does every family but dense: the gang's layer slice runs the dense
+layer body.
 
 Two virtual-clock modes:
 
@@ -54,6 +56,7 @@ from __future__ import annotations
 
 import functools
 import math
+import sys
 import time
 from collections import Counter, deque
 from typing import Callable, Dict, List, Optional, Tuple
@@ -67,7 +70,9 @@ from repro.configs.base import ModelConfig
 from repro.core.backend import ExecutionBackend
 from repro.core.request import Request
 from repro.core.simulator import Work
-from repro.serving.engine import PrefillState, ReplicaEngine, SlotsFull
+from repro.kernels import ops
+from repro.serving.engine import (FAMILIES, PrefillState, ReplicaEngine,
+                                  SlotsFull)
 from repro.sp.gang import (GangPrefillState, GangSPRunner, gang_degree,
                            make_gang_mesh, plan_for_gang)
 
@@ -118,7 +123,8 @@ class EngineBackend(ExecutionBackend):
                  token_provider: Optional[Callable[[Request],
                                                    Optional[np.ndarray]]] = None,
                  seed: int = 0, enable_sp: bool = True,
-                 sp_degree_cap: int = 0):
+                 sp_degree_cap: int = 0, long_threshold: int = 0,
+                 max_gang: int = 0):
         assert clock in ("measured", "analytic"), clock
         self.cfg = cfg
         self.params = params
@@ -129,7 +135,7 @@ class EngineBackend(ExecutionBackend):
         self.max_new_cap = max_new_cap
         self.token_provider = token_provider
         self.seed = seed
-        self.enable_sp = enable_sp
+        self.enable_sp = enable_sp and FAMILIES[cfg.family].gang
         self.sp_degree_cap = sp_degree_cap
         self.needs_finish = clock == "analytic"
         self.max_prompt = max(4, max_len - min(max_new_cap, 32) - 1)
@@ -152,6 +158,12 @@ class EngineBackend(ExecutionBackend):
         self._parked_decode: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
         self._pdone: Dict[int, int] = {}
         self._gang_runners: Dict[int, GangSPRunner] = {}   # by degree
+        #: device -> the weights there, shared by every gang runner
+        self._weight_copies: Dict = {}
+        #: `warmup` compiles every gang of 2 to `max_gang` replicas at each
+        #: of its lengths of `long_threshold` tokens or more (0: no gang)
+        self.long_threshold = long_threshold
+        self.max_gang = max_gang
         self.generated: Dict[int, List[int]] = {}         # request rid -> tokens
         #: request rid -> virtual time of each generated token: the first
         #: at prefill completion, each later one at its decode step's end
@@ -204,6 +216,9 @@ class EngineBackend(ExecutionBackend):
         on the given replicas, so measured virtual time reflects steady-state
         compute instead of charging first-shape compilation to whichever
         policy happens to run first."""
+        impl = ops.paged_decode_impl(self.cfg.head_dim)
+        print(f"decode attention: {self.cfg.name} runs {impl} "
+              f"(ops.paged_decode_impl)", file=sys.stderr)
         for rid in replica_ids:
             eng = self._engine(rid)
             for n in sorted(set(lengths)):
@@ -215,28 +230,34 @@ class EngineBackend(ExecutionBackend):
                 slot = eng.admit(-1, st)
                 eng.decode_iteration({slot: 0})
                 eng.evict(slot)
+        if self.enable_sp and self.max_gang > 1 and jax.device_count() > 1:
+            self.warmup_gang([n for n in lengths if n >= self.long_threshold],
+                             range(2, self.max_gang + 1),
+                             cluster_input_len=None)
 
     def warmup_gang(self, lengths, degrees, *,
-                    cluster_input_len: int = 300_000) -> None:
+                    cluster_input_len: Optional[int] = 300_000) -> None:
         """Pre-compile the gang-SP runners (embed, every quantum slice,
         logits) for the given engine-side prompt lengths and gang degrees,
-        with the inner strategy the planner picks at `cluster_input_len` —
-        the gang counterpart of `warmup`, keeping shard_map compilation out
+        with the inner strategy the planner picks at `cluster_input_len`
+        (None: at each length, as a request of that length plans) — the
+        gang counterpart of `warmup`, keeping shard_map compilation out
         of the measured clock and out of the `sp_timings` calibration
         samples."""
         for requested in sorted(set(degrees)):
             degree = gang_degree(requested, cap=self.sp_degree_cap)
             if degree < 2:
                 continue
-            mesh = make_gang_mesh(degree, self.cfg.num_heads)
-            plan = plan_for_gang(self.cfg, cluster_input_len, mesh)
             runner = self._runner_for(degree)
             for n in sorted(set(lengths)):
+                plan = plan_for_gang(self.cfg, cluster_input_len or int(n),
+                                     runner.mesh)
                 st = runner.start(-1, np.zeros(int(n), np.int32), plan)
                 done = False
                 while not done:
                     st, done = runner.quantum(st, self.lpq * degree)
                 runner.logits(st)
+                runner.gather_kv(st)
 
     def _engine(self, rid: int) -> ReplicaEngine:
         eng = self._engines.get(rid)
@@ -357,22 +378,22 @@ class EngineBackend(ExecutionBackend):
         dt += d
         self._first_token(req.rid, logits)
         self._kv[req.rid] = st
-        if req.prefix_group is not None and st.host_tokens is not None:
+        if (req.prefix_group is not None and st.host_tokens is not None
+                and not eng.kvpool.ring):
             # park the full prompt KV in THIS engine's prefix cache (admit
             # + release -> cached-free list) so the group's next request
-            # routed here skips the shared blocks.  Bookkeeping copy, off
-            # the virtual clock — the analytic model prices the skip via
+            # routed here skips the shared blocks (a pool with rings
+            # reuses no prefix).  Bookkeeping copy, off the virtual clock —
+            # the analytic model prices the skip via
             # prefill_time(cached_tokens=...), not this transfer.
-            k = jnp.stack(st.kv_k, 0)[:, 0]
-            v = jnp.stack(st.kv_v, 0)[:, 0]
-            if st.prefix_k is not None:
-                k = jnp.concatenate([st.prefix_k.astype(k.dtype), k], axis=2)
-                v = jnp.concatenate([st.prefix_v.astype(v.dtype), v], axis=2)
+            k, v, _, _ = eng.assemble_kv(st)
             eng.cache_prompt(0x40000000 ^ req.rid, k, v, st.host_tokens)
         return dt
 
     # ---- gang-scheduled SP prefill (§5.3) ----------------------------
     def _gang_degree_for(self, work: Work) -> int:
+        """1 (the single-replica path) unless the policy asked for fast SP
+        and a gang can prefill the model's family."""
         if not self.enable_sp or work.sp_mode != "fastsp":
             return 1
         return gang_degree(len(work.replica_ids), cap=self.sp_degree_cap)
@@ -381,7 +402,8 @@ class EngineBackend(ExecutionBackend):
         r = self._gang_runners.get(degree)
         if r is None:
             mesh = make_gang_mesh(degree, self.cfg.num_heads)
-            r = GangSPRunner(self.cfg, self.params, mesh)
+            r = GangSPRunner(self.cfg, self.params, mesh,
+                             self._weight_copies)
             self._gang_runners[degree] = r
         return r
 

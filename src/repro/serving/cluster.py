@@ -83,7 +83,10 @@ class MiniCluster:
             layers_per_quantum=layers_per_quantum, clock=clock,
             max_new_cap=1 << 30,                   # honor each max_new exactly
             token_provider=lambda r: self._tok.get(r.rid), seed=seed,
-            enable_sp=enable_sp, sp_degree_cap=sp_degree_cap)
+            enable_sp=enable_sp, sp_degree_cap=sp_degree_cap,
+            # a PecSched long may gang any of the general engines
+            long_threshold=long_threshold,
+            max_gang=n_engines if pecfam else 0)
         self._pending: List[ServeRequest] = []
         self.done: List[ServeRequest] = []
         self.summary: Dict = {}

@@ -16,15 +16,20 @@ active slot's new KV into its own block and attends over the pool in
 place, up to each slot's live length — one KV path for gang scatter,
 preemption eviction and decode alike.
 
-The engine targets the dense family (the paper's evaluation models are all
-dense); decode runs slot-batched with per-slot cache lengths — continuous
-batching at the iteration level.
+The engine serves the dense family (the paper's evaluation models) and
+afmoe (`models/afmoe.py`: windowed layers beside full ones, sigmoid-routed
+experts of which the chip holds a share).  For afmoe the pool keeps the
+windowed layers' KV in rings of the window (serving/kvcache.py): a prefill
+keeps only the last `sliding_window` positions of those layers, `admit`
+moves only those, and decode writes and reads each windowed layer's ring
+from the window's start.  Decode runs slot-batched with per-slot cache
+lengths — continuous batching at the iteration level.
 """
 from __future__ import annotations
 
 import functools
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +39,7 @@ from repro import obs
 from repro.configs.base import ModelConfig
 from repro.kernels import flash_decode as fd
 from repro.kernels import ops
+from repro.models import afmoe
 from repro.models import layers as L
 from repro.models import model as mdl
 from repro.serving.kvcache import PagedKVCache, PrefixHit
@@ -67,7 +73,9 @@ class PrefillState:
     tokens: jnp.ndarray                   # (1, S) int32 — ALWAYS full prompt
     x: jnp.ndarray                        # (1, S_suffix, d) — intermediate
     layer: int                            # next layer to execute
-    kv_k: List[jnp.ndarray] = field(default_factory=list)   # per-layer (1,KV,S,hd)
+    #: per layer (1, KV, S_suffix, hd); a windowed layer's keeps only its
+    #: last `sliding_window` positions
+    kv_k: List[jnp.ndarray] = field(default_factory=list)
     kv_v: List[jnp.ndarray] = field(default_factory=list)
     prefix_k: Optional[jnp.ndarray] = None   # (L, KV, P, hd) reused KV
     prefix_v: Optional[jnp.ndarray] = None
@@ -87,10 +95,11 @@ class PrefillState:
 # ---- compiled programs -----------------------------------------------------
 # Module-level and keyed on the (frozen, hashable) config, so every engine of
 # one model shares one program per (prompt length, quantum size).  The
-# weights are arguments, never constants folded into a program.
+# weights are arguments, never constants folded into a program.  What a
+# family changes in them is its `Family` entry (end of this section).
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _embed(params, tokens, *, cfg: ModelConfig):
-    return params["embed"][tokens].astype(jnp.dtype(cfg.dtype))
+    return FAMILIES[cfg.family].embed(cfg, params, tokens)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "n"))
@@ -98,25 +107,36 @@ def _prefill_slice(params, x, lo, pk, pv, *, cfg: ModelConfig, n: int):
     """Layers [lo, lo + n) over x (B, S, d).  With pk/pv ((n, KV, P, hd),
     the reused prefix KV of these layers) x covers only the suffix: RoPE
     starts at position P and attention runs over [prefix ‖ suffix] at query
-    offset P, through the same `_dense_layer` body as a full prefill."""
+    offset P, through the same layer body as a full prefill.  Returns x,
+    the layers' K, V and the (token, chosen expert) pairs that landed on
+    held experts."""
     B, S, _ = x.shape
     P = 0 if pk is None else pk.shape[2]
     positions = jnp.broadcast_to(jnp.arange(P, P + S)[None], (B, S))
 
-    def body(x, inp):
+    def prefix_attn(pkl, pvl):
+        if pkl is None:
+            return None
+
+        def attn_fn(qh, kh, vh, *, causal, sliding_window):
+            k = jnp.concatenate([pkl[None].astype(kh.dtype), kh], axis=2)
+            v = jnp.concatenate([pvl[None].astype(vh.dtype), vh], axis=2)
+            return ops.attention(qh, k, v, causal=causal,
+                                 sliding_window=sliding_window,
+                                 q_offset=P, impl="xla")
+        return attn_fn
+
+    layer = FAMILIES[cfg.family].prefill_layer
+
+    def body(carry, inp):
+        x, held = carry
         i, pkl, pvl = inp
-        attn_fn = None
-        if pkl is not None:
-            def attn_fn(qh, kh, vh, *, causal, sliding_window):
-                k = jnp.concatenate([pkl[None].astype(kh.dtype), kh], axis=2)
-                v = jnp.concatenate([pvl[None].astype(vh.dtype), vh], axis=2)
-                return ops.attention(qh, k, v, causal=causal,
-                                     sliding_window=sliding_window,
-                                     q_offset=P, impl="xla")
-        return mdl._dense_layer(cfg, mdl.layer_at(params["layers"], i), x,
-                                positions, sliding_window=cfg.sliding_window,
-                                impl="xla", write_cache=True, attn_fn=attn_fn)
-    return jax.lax.scan(body, x, (lo + jnp.arange(n), pk, pv))
+        x, kv, m = layer(cfg, params, i, x, positions, prefix_attn(pkl, pvl))
+        return (x, None if m is None else held + m), kv
+    held = None if cfg.held_experts == 0 else jnp.int32(0)
+    (x, held), kvs = jax.lax.scan(body, (x, held),
+                                  (lo + jnp.arange(n), pk, pv))
+    return x, kvs, held
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -140,7 +160,7 @@ def _decode(params, pool_k, pool_v, table, step, *, cfg: ModelConfig,
     it is what a step must not make.  On the host the caller's arrays stay
     valid, so a stand-in that hands its input pool back still runs (the
     benchmark's fault test of a step that leaves its state unchanged)."""
-    impl = impl or ops.paged_decode_impl(cfg.head_dim, cfg.sliding_window)
+    impl = impl or ops.paged_decode_impl(cfg.head_dim)
     program = _decode_program(jax.default_backend() != "cpu")
     return program(params, pool_k, pool_v, table, step, cfg=cfg, impl=impl)
 
@@ -196,8 +216,155 @@ def _decode_step(params, pool_k, pool_v, table, step, *, cfg, impl):
             pv.reshape(pool_v.shape))
 
 
-# the device trace and the benchmark's readers find the program by this name
+def _decode_afmoe(params, pools, table, wtable, step, *, cfg: ModelConfig,
+                  impl: Optional[str] = None):
+    """`_decode` for afmoe: pools = (K, V of the full layers (Lf, N, KV,
+    page, hd), K, V of the windowed layers' rings (Lw, Nw, KV, page,
+    hd)), returned updated; table (slots, P) each slot's full blocks,
+    wtable (slots, Pw) each slot's ring, logical block j at entry j %
+    ring.  A windowed layer writes the new KV into the ring and attends
+    over [length + 1 - window, length + 1); a full layer as the dense
+    step does.  Also returns the number of (active row, chosen expert)
+    pairs that landed on held experts."""
+    impl = impl or ops.paged_decode_impl(cfg.head_dim)
+    program = _decode_afmoe_program(jax.default_backend() != "cpu")
+    return program(params, *pools, table, wtable, step, cfg=cfg, impl=impl)
+
+
+@functools.lru_cache(maxsize=None)
+def _decode_afmoe_program(donate: bool):
+    return jax.jit(_decode_afmoe_step, static_argnames=("cfg", "impl"),
+                   donate_argnums=(1, 2, 3, 4) if donate else ())
+
+
+def _decode_afmoe_step(params, pool_k, pool_v, wpool_k, wpool_v, table,
+                       wtable, step, *, cfg, impl):
+    tok, lens, active = step[0], step[1], step[2] > 0
+    nf, nb, kvh, page, hd = pool_k.shape
+    nw, nwb = wpool_k.shape[:2]
+    B, H, W = tok.shape[0], cfg.num_heads, cfg.sliding_window
+    ring = -(-W // page) + 1
+    eps = cfg.norm_eps
+    blk = lens // page
+    dest = [jnp.where(active, jnp.take_along_axis(t, b[:, None], 1)[:, 0],
+                      n - 1)
+            for t, b, n in ((table, blk, nb), (wtable, blk % ring, nwb))]
+    off = jnp.where(active, lens % page, 0)
+    kv_len = jnp.where(active, lens + 1, 0)
+    # per layer: windowed or not, and its index among the layers of its
+    # kind.  A layer writes the other kind's pool too, into that pool's
+    # scratch block: a pool that passed through a `cond` branch unwritten
+    # would be copied whole, where a one-token write costs nothing.
+    slide = np.array([cfg.layer_window(i) > 0
+                      for i in range(cfg.num_layers)])
+    jf = np.minimum(np.cumsum(~slide) - ~slide, nf - 1)
+    jw = np.minimum(np.cumsum(slide) - slide, nw - 1)
+
+    def layer(i, carry, dense_lead):
+        x, pk, pv, wk, wv, held = carry
+        pl = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+            params["layers"])
+        sl, f, w = (jnp.asarray(a)[i] for a in (slide, jf, jw))
+        q, k, v, g = afmoe.project(cfg, pl["attn"],
+                                   L.rms_norm(x, pl["ln_in"], eps))
+        q = jnp.where(sl, L.rope(q[:, None], lens[:, None],
+                                 cfg.rope_theta)[:, 0], q)
+        k = jnp.where(sl, L.rope(k[:, None], lens[:, None],
+                                 cfg.rope_theta)[:, 0], k)
+        for s in range(B):
+            kk, vv = k[s][None, :, None], v[s][None, :, None]
+            at = (f * nb + jnp.where(sl, nb - 1, dest[0][s]), 0, off[s], 0)
+            pk = jax.lax.dynamic_update_slice(pk, kk.astype(pk.dtype), at)
+            pv = jax.lax.dynamic_update_slice(pv, vv.astype(pv.dtype), at)
+            at = (w * nwb + jnp.where(sl, dest[1][s], nwb - 1), 0, off[s], 0)
+            wk = jax.lax.dynamic_update_slice(wk, kk.astype(wk.dtype), at)
+            wv = jax.lax.dynamic_update_slice(wv, vv.astype(wv.dtype), at)
+        o = jax.lax.cond(
+            sl,
+            lambda: ops.paged_decode_attention(
+                q, wk, wv, wtable + w * nwb, kv_len, sliding_window=W,
+                ring=ring, impl=impl),
+            lambda: ops.paged_decode_attention(
+                q, pk, pv, table + f * nb, kv_len, impl=impl))
+        a = L.linear(o.reshape(B, H * hd) * g, pl["attn"]["wo"])
+        x = x + L.rms_norm(a, pl["ln_post_attn"], eps)
+        y, m = afmoe.mlp(cfg, params, i, L.rms_norm(x, pl["ln_pre_mlp"], eps),
+                         active, dense_lead=dense_lead)
+        x = x + L.rms_norm(y, pl["ln_post_mlp"], eps)
+        return x, pk, pv, wk, wv, held + m
+
+    carry = (afmoe.embed(cfg, params, tok),
+             pool_k.reshape(nf * nb, kvh, page, hd),
+             pool_v.reshape(nf * nb, kvh, page, hd),
+             wpool_k.reshape(nw * nwb, kvh, page, hd),
+             wpool_v.reshape(nw * nwb, kvh, page, hd), jnp.int32(0))
+    # the dense lead runs unrolled and the expert layers in a loop of
+    # their own, so each layer's MLP is known where it is traced: a `cond`
+    # between the two in one loop reads the lead's weights on every layer
+    nd = cfg.num_dense_layers
+    for i in range(nd):
+        carry = layer(i, carry, True)
+    carry = jax.lax.fori_loop(nd, cfg.num_layers,
+                              lambda i, c: layer(i, c, False), carry)
+    x, *pools, held = carry
+    shapes = (pool_k.shape, pool_v.shape, wpool_k.shape, wpool_v.shape)
+    return (mdl.lm_logits(cfg, params, x),
+            *(a.reshape(sh) for a, sh in zip(pools, shapes)), held)
+
+
+# the device trace and the benchmark's readers find the programs by this name
 _decode_step.__name__ = _decode_step.__qualname__ = "_decode"
+_decode_afmoe_step.__name__ = _decode_afmoe_step.__qualname__ = "_decode"
+
+
+@dataclass(frozen=True)
+class Family:
+    """What one model family puts into the engine's programs: its token
+    embedding, one prefill layer (cfg, params, i, x, positions, attn_fn) ->
+    (x, the layer's KV, held assignments), and its decode step (params,
+    the pool's arrays, the page tables, step) -> (logits, arrays, held
+    assignments).  `gang` says whether a §5.3 gang (`sp/gang.py`, the
+    dense layer body) can prefill it."""
+    embed: Callable
+    prefill_layer: Callable
+    decode: Callable
+    gang: bool
+
+
+def _dense_prefill_layer(cfg, params, i, x, positions, attn_fn):
+    x, kv = mdl._dense_layer(cfg, mdl.layer_at(params["layers"], i), x,
+                             positions, sliding_window=cfg.sliding_window,
+                             impl="xla", write_cache=True, attn_fn=attn_fn)
+    return x, kv, None
+
+
+def _afmoe_prefill_layer(cfg, params, i, x, positions, attn_fn):
+    x, (k, v), held = afmoe.layer(cfg, params, i, x, positions,
+                                  attn_fn=attn_fn)
+    return x, L.KVCache(k=k, v=v), held
+
+
+def _dense_decode(params, arrays, tables, step, *, cfg):
+    logits, k, v = _decode(params, *arrays, *tables, step, cfg=cfg)
+    return logits, (k, v), None
+
+
+def _afmoe_decode(params, arrays, tables, step, *, cfg):
+    logits, *arrays, held = _decode_afmoe(params, arrays, *tables, step,
+                                          cfg=cfg)
+    return logits, arrays, held
+
+
+FAMILIES = {
+    "dense": Family(
+        embed=lambda cfg, params, tokens:
+            params["embed"][tokens].astype(jnp.dtype(cfg.dtype)),
+        prefill_layer=_dense_prefill_layer, decode=_dense_decode,
+        gang=True),
+    "afmoe": Family(embed=afmoe.embed, prefill_layer=_afmoe_prefill_layer,
+                    decode=_afmoe_decode, gang=False),
+}
 
 
 class ReplicaEngine:
@@ -207,7 +374,10 @@ class ReplicaEngine:
     def __init__(self, cfg: ModelConfig, params, *, max_slots: int = 8,
                  max_len: int = 512, layers_per_quantum: int = 2,
                  block_size: int = 16, n_blocks: Optional[int] = None):
-        assert cfg.family in ("dense",), "engine demo targets dense family"
+        if cfg.family not in FAMILIES:
+            raise ValueError(f"the engine serves the {tuple(FAMILIES)} "
+                             f"families, not {cfg.family!r}")
+        self.family = FAMILIES[cfg.family]
         self.cfg = cfg
         self.params = params
         self.max_slots = max_slots
@@ -228,17 +398,30 @@ class ReplicaEngine:
         # the binding constraint.
         n_blocks = (n_blocks if n_blocks is not None
                     else (max_slots + 1) * self.blocks_per_seq)
+        #: windowed layers (afmoe) keep rings of the window, the same
+        #: budget of sequences deep
+        self.window_layers = cfg.window_layers
+        ring = -(-cfg.sliding_window // block_size) + 1 \
+            if self.window_layers else 0
+        n_ring = (max_slots + 1) * min(self.blocks_per_seq, ring)
         # one block more than the budget, the last, which no table owns:
         # decode writes of inactive slots and page-table padding point at it
-        self.kvpool = PagedKVCache.create(nl, n_blocks + 1, KV, block_size,
-                                          hd, dt)
+        self.kvpool = PagedKVCache.create(
+            nl, n_blocks + 1, KV, block_size, hd, dt,
+            window_layers=self.window_layers, window=cfg.sliding_window,
+            n_window_blocks=n_ring + 1 if ring else 0)
         self.scratch = self.kvpool.free.pop()
-        #: decode page table width: the kernel walks it a whole number of
-        #: steps at a time
-        self.pages_per_seq = -(-self.blocks_per_seq // fd.PAGES_PER_STEP) \
-            * fd.PAGES_PER_STEP
+        self.wscratch = self.kvpool.wfree.pop() if ring else None
+        #: decode page table widths: the kernel walks a table a whole
+        #: number of steps at a time
+        self.pages_per_seq = self._width(self.blocks_per_seq)
+        self.ring_width = self._width(ring) if ring else 0
         self.slot_rid: List[Optional[int]] = [None] * max_slots
-        self._table = None           # device page table, rebuilt on rebinding
+        self._table = None           # device page tables, rebuilt on rebinding
+
+    @staticmethod
+    def _width(pages: int) -> int:
+        return -(-pages // fd.PAGES_PER_STEP) * fd.PAGES_PER_STEP
 
     # ---- prefill (preemptible) ---------------------------------------------
     def start_prefill(self, rid: int, tokens: jnp.ndarray,
@@ -268,13 +451,24 @@ class ReplicaEngine:
             if st.prefix_k is not None:
                 pk, pv = st.prefix_k[lo:hi], st.prefix_v[lo:hi]
             with obs.span("prefill_quantum/program"):
-                x, kvs = _prefill_slice(self.params, st.x, lo, pk, pv,
-                                        cfg=self.cfg, n=hi - lo)
+                out = _prefill_slice(self.params, st.x, lo, pk, pv,
+                                     cfg=self.cfg, n=hi - lo)
+            x, kvs, held = out
+            S = x.shape[1]
+            if self.cfg.held_experts:
+                obs.count_device("moe/held_assignments", held)
+                obs.count("moe/token_layers",
+                          S * len(range(max(lo, self.cfg.num_dense_layers),
+                                        hi)))
             st.x = x
+            keep = min(S, self.cfg.sliding_window)
             with obs.span("prefill_quantum/kv_split"):
                 for i in range(hi - lo):
-                    st.kv_k.append(kvs.k[i])
-                    st.kv_v.append(kvs.v[i])
+                    k, v = kvs.k[i], kvs.v[i]
+                    if lo + i in self.window_layers:   # the window's tail
+                        k, v = k[:, :, S - keep:], v[:, :, S - keep:]
+                    st.kv_k.append(k)
+                    st.kv_v.append(v)
             st.layer = hi
         return st, hi == self.cfg.num_layers
 
@@ -389,29 +583,49 @@ class ReplicaEngine:
             raise ValueError("sequence longer than engine max_len")
         # full decode budget (cached-free blocks are evictable, so they
         # count as available)
-        if (len(self.kvpool.free) + len(self.kvpool.cached)
-                < self.blocks_per_seq):
+        pool = self.kvpool
+        if (len(pool.free) + len(pool.cached) < self.blocks_per_seq
+                or len(pool.wfree) < pool.ring_needed(self.max_len)):
             raise SlotsFull(
                 f"KV pool cannot reserve a decode lane for request {rid}: "
-                f"{len(self.kvpool.free)} of {self.kvpool.n_blocks} "
-                f"blocks free, {self.blocks_per_seq} needed")
+                f"{len(pool.free)} of {pool.n_blocks} blocks and "
+                f"{len(pool.wfree)} ring blocks free, {self.blocks_per_seq} "
+                f"and {pool.ring_needed(self.max_len)} needed")
         with obs.span("admit", rid=rid):
             with obs.span("admit/assemble"):
-                k = jnp.stack(st.kv_k, 0)[:, 0]      # (L, KV, S, hd)
-                v = jnp.stack(st.kv_v, 0)[:, 0]
-                if st.prefix_k is not None:      # re-assemble FULL-sequence KV
-                    k = jnp.concatenate([st.prefix_k.astype(k.dtype), k],
-                                        axis=2)
-                    v = jnp.concatenate([st.prefix_v.astype(v.dtype), v],
-                                        axis=2)
+                k, v, wk, wv = self.assemble_kv(st)
             with obs.span("admit/pool_write"):
-                self.kvpool.admit(rid, k, v, tokens=st.host_tokens)
-                self.kvpool.reserve(rid, self.max_len)
+                pool.admit(rid, k, v, tokens=st.host_tokens, wk=wk, wv=wv)
+                pool.reserve(rid, self.max_len)
             obs.count("admit/tokens", S)
+            if pool.ring:
+                obs.count("admit/blocks_full",
+                          len(pool.full_layers) * len(pool.tables[rid]))
+                obs.count("admit/blocks_window",
+                          len(pool.window_layers) * len(pool.rings[rid]))
             slot = free[0]
             self.slot_rid[slot] = rid
             self._table = None
         return slot
+
+    def assemble_kv(self, st: PrefillState):
+        """A finished prefill's KV over its FULL sequence, the prefix's
+        included: (k, v, None, None) with k, v (L, KV, S, hd); with
+        windowed layers (k, v) of the full layers and (wk, wv) (Lw, KV, T,
+        hd) the windowed layers' last T = min(S, window) positions, all a
+        prefill keeps of them (a pool with rings reuses no prefix)."""
+        def stack(kv, layers):
+            return jnp.stack([kv[i] for i in layers], 0)[:, 0]
+        if self.window_layers:
+            full, win = self.cfg.full_layers, self.window_layers
+            return (stack(st.kv_k, full), stack(st.kv_v, full),
+                    stack(st.kv_k, win), stack(st.kv_v, win))
+        layers = range(self.cfg.num_layers)
+        k, v = stack(st.kv_k, layers), stack(st.kv_v, layers)
+        if st.prefix_k is not None:      # re-assemble FULL-sequence KV
+            k = jnp.concatenate([st.prefix_k.astype(k.dtype), k], axis=2)
+            v = jnp.concatenate([st.prefix_v.astype(v.dtype), v], axis=2)
+        return k, v, None, None
 
     def evict(self, slot: int) -> None:
         rid = self.slot_rid[slot]
@@ -427,13 +641,23 @@ class ReplicaEngine:
     def _page_table(self) -> jax.Array:
         """(max_slots, pages_per_seq) int32: each bound slot's blocks, the
         rest of its row and every unbound row the scratch block."""
+        return self._tables()[0]
+
+    def _tables(self) -> Tuple[jax.Array, ...]:
+        """The decode program's page tables: `_page_table`, then with
+        windowed layers the slots' rings likewise."""
         if self._table is None:
-            t = np.full((self.max_slots, self.pages_per_seq), self.scratch,
-                        np.int32)
-            for s, rid in enumerate(self.slot_rid):
-                blocks = self.kvpool.tables.get(rid, ())
-                t[s, :len(blocks)] = blocks
-            self._table = jnp.asarray(t)
+            def table(width, scratch, rows):
+                t = np.full((self.max_slots, width), scratch, np.int32)
+                for s, rid in enumerate(self.slot_rid):
+                    blocks = rows.get(rid, ())
+                    t[s, :len(blocks)] = blocks
+                return jnp.asarray(t)
+            full = table(self.pages_per_seq, self.scratch,
+                         self.kvpool.tables)
+            self._table = (full,) if not self.window_layers else (
+                full, table(self.ring_width, self.wscratch,
+                            self.kvpool.rings))
         return self._table
 
     def decode_logits(self, tokens: Dict[int, int]) -> jax.Array:
@@ -455,16 +679,23 @@ class ReplicaEngine:
                 if self.kvpool.own_block(rid, lens[s]):
                     self._table = None
                 step[0, s], step[2, s] = t, 1
-            table = self._page_table()
+            tables = self._tables()
             step = jnp.asarray(step)
+        pool = self.kvpool
         with obs.span("decode_iteration/program"):
-            logits, self.kvpool.k, self.kvpool.v = _decode(
-                self.params, self.kvpool.k, self.kvpool.v, table, step,
-                cfg=self.cfg)
+            logits, pool.arrays, held = self.family.decode(
+                self.params, pool.arrays, tables, step, cfg=self.cfg)
+            if held is not None:
+                obs.count_device("moe/held_assignments", held)
+                obs.count("moe/token_layers", len(tokens) * (
+                    self.cfg.num_layers - self.cfg.num_dense_layers))
         for s in tokens:
-            self.kvpool.lengths[self.slot_rid[s]] += 1
+            pool.lengths[self.slot_rid[s]] += 1
         obs.count("decode_iteration/slots", len(tokens))
         obs.count("decode_iteration/kv_tokens", sum(lens[s] for s in tokens))
+        if pool.ring:
+            obs.count("decode_iteration/window_kv_tokens",
+                      sum(min(lens[s], pool.window) for s in tokens))
         return logits
 
     def decode_iteration(self, tokens: Dict[int, int]) -> Dict[int, int]:

@@ -16,6 +16,16 @@ survives between requests — the property cross-turn chat reuse depends on.
 Tables are copy-on-write: a write into a block another table still
 references forks a private copy first (`own_block`).
 
+Two kinds of layer (a model with windowed layers beside full ones, given
+`window_layers` at creation): full layers keep the growing tables above;
+windowed layers keep, per request, a RING of `ceil(window / block) + 1`
+blocks in a pool of their own, the logical block j of the sequence in ring
+slot j % ring, so a block whose positions fell out of every later window
+takes the next positions in place.  A request then holds
+`full_layers x ceil(len / block)` plus `window_layers x min(ceil(len /
+block), ring)` layer-blocks.  Ring blocks are never shared, and a pool
+with rings reuses no prefix: `lookup_prefix` finds no hit there.
+
 Pure JAX: the pool is a pytree; allocation metadata is host-side (block
 tables are tiny and scheduler-owned, exactly as in vLLM).
 """
@@ -90,26 +100,81 @@ class PagedKVCache:
         field(default_factory=dict)
     stats: Dict[str, int] = field(default_factory=lambda: dict(
         lookups=0, hits=0, hit_tokens=0, blocks_shared=0, cow_forks=0))
+    # --- windowed layers: ring pool (None without window layers) ---
+    wk: Optional[jax.Array] = None          # (Lw, Nw, KV, block, hd)
+    wv: Optional[jax.Array] = None
+    window: int = 0
+    #: layer index of each windowed layer, in the order of wk's first axis
+    window_layers: Tuple[int, ...] = ()
+    n_layers: int = 0
+    wfree: List[int] = field(default_factory=list)
+    rings: Dict[int, List[int]] = field(default_factory=dict)  # rid -> ring
 
     # ------------------------------------------------------------------
     @classmethod
     def create(cls, n_layers: int, n_blocks: int, kv_heads: int,
-               block_size: int, head_dim: int, dtype=jnp.bfloat16
-               ) -> "PagedKVCache":
-        shape = (n_layers, n_blocks, kv_heads, block_size, head_dim)
-        return cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
-                   block_size=block_size, free=list(range(n_blocks)))
+               block_size: int, head_dim: int, dtype=jnp.bfloat16, *,
+               window_layers: Sequence[int] = (), window: int = 0,
+               n_window_blocks: int = 0) -> "PagedKVCache":
+        """`n_layers` layers, of which `window_layers` keep rings of the
+        `window` in `n_window_blocks` blocks of their own; `n_blocks`
+        blocks for the others."""
+        n_full = n_layers - len(window_layers)
+        shape = (n_full, n_blocks, kv_heads, block_size, head_dim)
+        pool = cls(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
+                   block_size=block_size, free=list(range(n_blocks)),
+                   n_layers=n_layers)
+        if window_layers:
+            wshape = (len(window_layers), n_window_blocks, kv_heads,
+                      block_size, head_dim)
+            pool.wk, pool.wv = jnp.zeros(wshape, dtype), \
+                jnp.zeros(wshape, dtype)
+            pool.window, pool.window_layers = window, tuple(window_layers)
+            pool.wfree = list(range(n_window_blocks))
+        return pool
+
+    @property
+    def arrays(self) -> Tuple[jax.Array, ...]:
+        """The pool's device arrays, as the decode programs take them:
+        (k, v), then (wk, wv) with windowed layers."""
+        return (self.k, self.v) + ((self.wk, self.wv) if self.ring else ())
+
+    @arrays.setter
+    def arrays(self, arrays: Sequence[jax.Array]) -> None:
+        self.k, self.v, *ring = arrays
+        if ring:
+            self.wk, self.wv = ring
 
     @property
     def n_blocks(self) -> int:
         return self.k.shape[1]
 
+    @property
+    def ring(self) -> int:
+        """Blocks of a windowed layer's ring: any `window` consecutive
+        positions and the next one span at most this many (0: no ring)."""
+        return -(-self.window // self.block_size) + 1 if self.window else 0
+
+    @property
+    def full_layers(self) -> Tuple[int, ...]:
+        ring = set(self.window_layers)
+        return tuple(i for i in range(self.n_layers) if i not in ring)
+
     def blocks_needed(self, tokens: int) -> int:
         return -(-tokens // self.block_size)
 
+    def ring_needed(self, tokens: int) -> int:
+        return min(self.blocks_needed(tokens), self.ring)
+
+    def layer_blocks(self, tokens: int) -> Tuple[int, int]:
+        """(full, windowed) layer-blocks `tokens` positions hold."""
+        return (len(self.full_layers) * self.blocks_needed(tokens),
+                len(self.window_layers) * self.ring_needed(tokens))
+
     def can_admit(self, tokens: int) -> bool:
         return (len(self.free) + len(self.cached)
-                >= self.blocks_needed(tokens))
+                >= self.blocks_needed(tokens)
+                and len(self.wfree) >= self.ring_needed(tokens))
 
     # ------------------------------------------------------------------
     # allocation: blank blocks first, then LRU-evict the cached-prefix list
@@ -152,9 +217,12 @@ class PagedKVCache:
         via the chain-hash index, plus a partial match inside one registered
         tail block.  Read-only (no refcounts taken); callers that need the
         blocks to survive a subsequent allocation must `admit` (full blocks)
-        or `gather_prefix` (copy out) before allocating."""
+        or `gather_prefix` (copy out) before allocating.  A pool with
+        rings finds none: its windowed layers keep no prefix's KV."""
         hit = PrefixHit()
         self.stats["lookups"] += 1
+        if self.ring:
+            return hit
         bs = self.block_size
         h: Optional[int] = None
         i = 0
@@ -187,6 +255,9 @@ class PagedKVCache:
                 hit.tail_block, hit.tail_tokens = best_b, best_n
                 if best_b in self.cached:
                     self.cached.move_to_end(best_b)
+        return self._count_hit(hit)
+
+    def _count_hit(self, hit: PrefixHit) -> PrefixHit:
         if hit.total_tokens:
             self.stats["hits"] += 1
             self.stats["hit_tokens"] += hit.total_tokens
@@ -198,21 +269,40 @@ class PagedKVCache:
         blocks = list(hit.blocks)
         if hit.tail_block is not None:
             blocks.append(hit.tail_block)
-        idx = jnp.asarray(blocks)
-        k = self.k[:, idx]                     # (L, n, KV, bs, hd)
-        v = self.v[:, idx]
+        return self._contiguous(self.k, self.v, blocks, hit.total_tokens)
+
+    @staticmethod
+    def _contiguous(pk, pv, blocks: List[int], T: int):
+        idx = jnp.asarray(blocks, jnp.int32)
+        k, v = pk[:, idx], pv[:, idx]          # (L, n, KV, bs, hd)
         L, n, KV, bs, hd = k.shape
-        T = hit.total_tokens
         k = k.transpose(0, 2, 1, 3, 4).reshape(L, KV, n * bs, hd)[:, :, :T]
         v = v.transpose(0, 2, 1, 3, 4).reshape(L, KV, n * bs, hd)[:, :, :T]
         return k, v
 
+    def _interleave(self, full: jax.Array, win: jax.Array) -> jax.Array:
+        """Full-layer and windowed-layer stacks back in layer order."""
+        order = self.full_layers + self.window_layers
+        inv = [order.index(i) for i in range(self.n_layers)]
+        return jnp.concatenate([full, win.astype(full.dtype)], 0)[
+            jnp.asarray(inv)]
+
+    def split_layers(self, x: jax.Array):
+        """(L, ...) in layer order -> (full layers, windowed layers)."""
+        return (x[jnp.asarray(self.full_layers)],
+                x[jnp.asarray(self.window_layers)])
+
     # ------------------------------------------------------------------
     def admit(self, rid: int, k: jax.Array, v: jax.Array,
-              tokens: Optional[Sequence[int]] = None) -> PrefixHit:
+              tokens: Optional[Sequence[int]] = None, *,
+              wk: Optional[jax.Array] = None,
+              wv: Optional[jax.Array] = None) -> PrefixHit:
         """Install a request's prefill KV. k/v: (L, KV, S, hd) — always the
         FULL sequence (a cache-hit caller still passes full KV; the matched
-        blocks' slices simply are not written).
+        blocks' slices simply are not written).  With windowed layers, k/v
+        hold every layer and the windowed ones keep the last `window`
+        positions; or k/v hold the full layers only and wk/wv (Lw, KV, T,
+        hd) the windowed layers' last T positions of the S.
 
         With `tokens` (the prompt's token ids), fully-matched resident
         blocks are linked into the table by reference (refcount++, data
@@ -226,6 +316,15 @@ class PagedKVCache:
         S = k.shape[2]
         bs = self.block_size
         need = self.blocks_needed(S)
+        if self.ring:
+            if wk is None:
+                k, wk = self.split_layers(k)
+                v, wv = self.split_layers(v)
+                T = min(S, self.window)
+                wk, wv = wk[:, :, S - T:], wv[:, :, S - T:]
+            if len(self.wfree) < self.ring_needed(S):
+                raise MemoryError(f"need {self.ring_needed(S)} ring blocks, "
+                                  f"{len(self.wfree)} free")
         hit = PrefixHit()
         shared: List[int] = []
         if tokens is not None:
@@ -263,10 +362,28 @@ class PagedKVCache:
         table = shared + new_blocks
         self.tables[rid] = table
         self.lengths[rid] = S
+        if self.ring:
+            self._write_ring(rid, wk, wv, S)
         if tokens is not None:
             self._register(table, tokens)
             self.stats["blocks_shared"] += n_shared
         return hit
+
+    def _write_ring(self, rid: int, wk: jax.Array, wv: jax.Array,
+                    S: int) -> None:
+        """Allocate a ring for S positions and write the windowed layers'
+        last T = wk.shape[2] of them: logical block j into ring slot
+        j % ring."""
+        bs, T = self.block_size, wk.shape[2]
+        ring = [self.wfree.pop() for _ in range(self.ring_needed(S))]
+        self.rings[rid] = ring
+        first, last = (S - T) // bs, (S - 1) // bs
+        front = (0, 0), (0, 0), (S - T - first * bs, 0), (0, 0)
+        self.wk, self.wv = _write_blocks(
+            self.wk, self.wv,
+            jnp.asarray([ring[j % self.ring] for j in range(first, last + 1)],
+                        jnp.int32),
+            jnp.pad(wk, front), jnp.pad(wv, front))
 
     def _register(self, table: List[int], tokens: Sequence[int]) -> None:
         """Index a freshly-admitted table: chain hashes for full blocks,
@@ -295,7 +412,23 @@ class PagedKVCache:
         """Make the block that takes token `pos` of `rid` its own, so a
         write there disturbs no other table: grow the table by a block at
         its end, or fork a block another table shares (copy-on-write).
-        Returns whether the table changed."""
+        Returns whether the table changed.  On windowed layers the ring
+        slot of `pos` grows the ring, or is reused in place: the block's
+        old positions are out of every window from here on."""
+        grew = self._own_ring_block(rid, pos) if self.ring else False
+        return self._own_full_block(rid, pos) or grew
+
+    def _own_ring_block(self, rid: int, pos: int) -> bool:
+        ring = self.rings[rid]
+        slot = (pos // self.block_size) % self.ring
+        if slot < len(ring):
+            return False
+        if not self.wfree:
+            raise MemoryError("no free ring block")
+        ring.append(self.wfree.pop())
+        return True
+
+    def _own_full_block(self, rid: int, pos: int) -> bool:
         blocks = self.tables[rid]
         bi = pos // self.block_size
         if bi >= len(blocks):
@@ -316,25 +449,38 @@ class PagedKVCache:
 
     def append_token(self, rid: int, k: jax.Array, v: jax.Array) -> None:
         """Append one token's KV. k/v: (L, KV, hd), into a block of the
-        request's own (`own_block`)."""
+        request's own (`own_block`); on windowed layers into its ring."""
         pos = self.lengths[rid]
         self.own_block(rid, pos)
-        b = self.tables[rid][pos // self.block_size]
         off = pos % self.block_size
+        if self.ring:
+            k, wk = self.split_layers(k)
+            v, wv = self.split_layers(v)
+            r = self.rings[rid][(pos // self.block_size) % self.ring]
+            self.wk = self.wk.at[:, r, :, off].set(wk)
+            self.wv = self.wv.at[:, r, :, off].set(wv)
+        b = self.tables[rid][pos // self.block_size]
         self.k = self.k.at[:, b, :, off].set(k)
         self.v = self.v.at[:, b, :, off].set(v)
         self.lengths[rid] = pos + 1
 
     def gather(self, rid: int):
-        """A request's KV, contiguous: (L, KV, S, hd)."""
-        blocks = jnp.asarray(self.tables[rid])
+        """A request's KV, contiguous: (L, KV, S, hd).  On windowed
+        layers the positions out of the next token's window are zeros."""
         S = self.lengths[rid]
-        k = self.k[:, blocks]          # (L, n, KV, bs, hd)
-        v = self.v[:, blocks]
-        L, n, KV, bs, hd = k.shape
-        k = k.transpose(0, 2, 1, 3, 4).reshape(L, KV, n * bs, hd)[:, :, :S]
-        v = v.transpose(0, 2, 1, 3, 4).reshape(L, KV, n * bs, hd)[:, :, :S]
-        return k, v
+        k, v = self._contiguous(self.k, self.v, self.tables[rid], S)
+        if not self.ring:
+            return k, v
+        bs, ring = self.block_size, self.rings[rid]
+        first = max(0, S - self.window) // bs
+        blocks = [ring[j % self.ring] for j in range(first, -(-S // bs))]
+        wk, wv = self._contiguous(self.wk, self.wv, blocks, S - first * bs)
+        lo = max(0, S - self.window)
+        keep = (jnp.arange(S - first * bs) >= lo - first * bs)[:, None]
+        pad = ((0, 0), (0, 0), (first * bs, 0), (0, 0))
+        wk = jnp.pad(jnp.where(keep, wk, 0), pad)
+        wv = jnp.pad(jnp.where(keep, wv, 0), pad)
+        return self._interleave(k, wk), self._interleave(v, wv)
 
     def reserve(self, rid: int, capacity_tokens: int) -> None:
         """Grow a resident request's block table to hold `capacity_tokens`
@@ -345,6 +491,16 @@ class PagedKVCache:
         blocks)."""
         blocks = self.tables[rid]
         need = self.blocks_needed(capacity_tokens) - len(blocks)
+        if self.ring:
+            ring = self.rings[rid]
+            wneed = self.ring_needed(capacity_tokens) - len(ring)
+            if wneed > len(self.wfree) or len(self.free) + len(
+                    self.cached) < max(need, 0):
+                raise MemoryError(f"need {max(need, 0)} blocks and "
+                                  f"{wneed} ring blocks to reserve "
+                                  f"{capacity_tokens} tokens for rid {rid}")
+            for _ in range(max(wneed, 0)):
+                ring.append(self.wfree.pop())
         if need <= 0:
             return
         if len(self.free) + len(self.cached) < need:
@@ -371,6 +527,7 @@ class PagedKVCache:
         for b in reversed(self.tables.pop(rid)):
             self._release_block(b)
         self.lengths.pop(rid)
+        self.wfree.extend(self.rings.pop(rid, ()))
 
     def drop_cache(self) -> None:
         """Forget every cached prefix: parked blocks return to the blank

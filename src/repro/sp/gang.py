@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass, field
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -156,19 +156,41 @@ class GangPrefillState:
     kv_v: List[jax.Array] = field(default_factory=list)  # (n, 1, KV, S_pad, hd)
 
 
+def replicate(params, mesh: Mesh, copies: Dict):
+    """`params` replicated over `mesh`, assembled from one copy per device
+    kept in `copies` (device -> pytree) and made there on first use; a
+    device that already holds `params` (the engines' device) lends them as
+    they are.  Meshes that share a `copies` share one placement: a device
+    holds the weights once, however many gangs it serves in."""
+    devs = list(mesh.devices.flat)
+    for d in devs:
+        if d not in copies:
+            on_d = all(leaf.devices() == {d}
+                       for leaf in jax.tree.leaves(params))
+            copies[d] = params if on_d else jax.device_put(params, d)
+    sharding = NamedSharding(mesh, P())
+    return jax.tree.map(
+        lambda *shards: jax.make_array_from_single_device_arrays(
+            shards[0].shape, sharding, list(shards)),
+        *[copies[d] for d in devs])
+
+
 class GangSPRunner:
     """Gang-SP prefill for one (model, mesh).
 
     The EngineBackend keeps one runner per gang degree.  The weights are
-    placed replicated on the mesh once, here, and enter every program as
-    arguments; the inner strategy (`SPPlan.inner_impl` of each request's
-    plan) selects the compiled slice, so a policy sweep pays the shard_map
-    compilation once per (prompt bucket, strategy)."""
+    replicated on the mesh from `copies` (`replicate`; a runner of its own
+    places them afresh) and enter every program as arguments; the inner
+    strategy (`SPPlan.inner_impl` of each request's plan) selects the
+    compiled slice, so a policy sweep pays the shard_map compilation once
+    per (prompt bucket, strategy)."""
 
-    def __init__(self, cfg: ModelConfig, params, mesh: Mesh):
+    def __init__(self, cfg: ModelConfig, params, mesh: Mesh,
+                 copies: Optional[Dict] = None):
         self.cfg = cfg
         self.mesh = mesh
-        self.params = jax.device_put(params, NamedSharding(mesh, P()))
+        self.params = replicate(params, mesh,
+                                {} if copies is None else copies)
         self.degree = int(np.prod([mesh.shape[a] for a in SEQ_AXES]))
 
     # ------------------------------------------------------------------

@@ -90,22 +90,24 @@ def test_flash_decode_matches_reference(win, bk):
 
 
 PAGED_CASES = [
-    # h, kv, page, pages per sequence, pages per step, lengths, window
-    (8, 2, 4, 8, 4, (5, 0, 31, 16), 0),        # 4 q heads per KV head
-    (14, 2, 8, 4, 2, (1, 32, 17), 0),          # 7
-    (4, 4, 4, 6, 6, (24, 3), 0),               # MHA, one step per sequence
-    (8, 2, 4, 8, 4, (5, 30), 6),               # window: the XLA path only
+    # h, kv, page, pages per sequence, pages per step, lengths, window,
+    # ring (0: the table lists a sequence's pages in order)
+    (8, 2, 4, 8, 4, (5, 0, 31, 16), 0, 0),     # 4 q heads per KV head
+    (14, 2, 8, 4, 2, (1, 32, 17), 0, 0),       # 7
+    (4, 4, 4, 6, 6, (24, 3), 0, 0),            # MHA, one step per sequence
+    (8, 2, 4, 8, 4, (5, 30), 6, 0),            # a window
+    (8, 2, 4, 4, 2, (5, 30, 9, 12), 6, 3),     # a window over a 3-page ring
 ]
 
 
 @pytest.mark.parametrize("case,impl", [
-    (c, impl) for c in PAGED_CASES for impl in ("xla", "pallas_interpret")
-    if impl == "xla" or not c[-1]])
+    (c, impl) for c in PAGED_CASES for impl in ("xla", "pallas_interpret")])
 def test_paged_decode_matches_reference_on_gathered_pages(case, impl):
     """Attention through a page table into a shared pool == the reference
-    over each sequence's pages gathered in order; a length of 0 gives
-    zeros from the kernel."""
-    h, kv, page, n, per_step, lens, win = case
+    over each sequence's pages gathered in order (with a ring, logical
+    page j from entry j % ring); a length of 0 gives zeros from the
+    kernel."""
+    h, kv, page, n, per_step, lens, win, ring = case
     d, n_pool = 16, 40
     b = len(lens)
     kp, vp = t(n_pool, kv, page, d), t(n_pool, kv, page, d)
@@ -113,15 +115,19 @@ def test_paged_decode_matches_reference_on_gathered_pages(case, impl):
                                   for _ in range(b)]), jnp.int32)
     q = t(b, h, d)
     cl = jnp.asarray(lens, jnp.int32)
-    k = kp[pages].transpose(0, 2, 1, 3, 4).reshape(b, kv, n * page, d)
-    v = vp[pages].transpose(0, 2, 1, 3, 4).reshape(b, kv, n * page, d)
+    n_log = -(-max(lens) // page) if ring else n
+    logical = pages[:, np.arange(n_log) % ring] if ring else pages
+    k = kp[logical].transpose(0, 2, 1, 3, 4).reshape(b, kv, n_log * page, d)
+    v = vp[logical].transpose(0, 2, 1, 3, 4).reshape(b, kv, n_log * page, d)
     want = np.asarray(ref.decode_attention_reference(q, k, v, cl,
                                                      sliding_window=win))
     if impl == "xla":
         got = ops.paged_decode_attention(q, kp, vp, pages, cl,
-                                         sliding_window=win, impl="xla")
+                                         sliding_window=win, ring=ring,
+                                         impl="xla")
     else:
-        got = paged_flash_decode(q, kp, vp, pages, cl,
+        starts = jnp.maximum(cl - win, 0) if win else None
+        got = paged_flash_decode(q, kp, vp, pages, cl, starts, ring=ring,
                                  pages_per_step=per_step, interpret=True)
     got = np.asarray(got)
     live = np.asarray(lens) > 0

@@ -134,3 +134,81 @@ def test_paged_equals_dense_decode_attention():
         paged = ops.decode_attention(q, kg[layer][None], vg[layer][None], cl,
                                      impl="xla")
         np.testing.assert_allclose(dense, paged, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# two kinds of layer: full tables beside rings of the window
+# ---------------------------------------------------------------------------
+WIN_LAYERS, WINDOW = (0, 1, 2), 16        # layers 0-2 windowed, 3 full
+
+
+def _two_kind_pool(n_blocks=40, n_ring=12):
+    return PagedKVCache.create(4, n_blocks, 1, 8, 2, dtype=jnp.float32,
+                               window_layers=WIN_LAYERS, window=WINDOW,
+                               n_window_blocks=n_ring)
+
+
+def _kv(n, start=0, rid=0):
+    """Token t of every layer l holds rid * 1000 + l * 100 + t."""
+    t = rid * 1000 + np.arange(start, start + n, dtype=np.float32)
+    vals = t[None, :] + 100.0 * np.arange(4, dtype=np.float32)[:, None]
+    k = np.broadcast_to(vals[:, None, :, None], (4, 1, n, 2))
+    return jnp.asarray(k), jnp.asarray(k + 0.5)
+
+
+def test_two_kinds_count_full_blocks_and_capped_rings():
+    """A request holds full_layers x ceil(len / block) plus window_layers x
+    min(ceil(len / block), ring) layer-blocks; `can_admit` and `reserve`
+    check both kinds, and a release returns both."""
+    pc = _two_kind_pool()
+    assert pc.ring == 3                         # ceil(16 / 8) + 1
+    assert pc.layer_blocks(10) == (1 * 2, 3 * 2)
+    assert pc.layer_blocks(100) == (1 * 13, 3 * 3)
+    pc.admit(0, *_kv(100))
+    assert len(pc.tables[0]) == 13 and len(pc.rings[0]) == 3
+    pc.reserve(0, 200)                          # full grows, ring capped
+    assert len(pc.tables[0]) == 25 and len(pc.rings[0]) == 3
+    assert pc.can_admit(20) and not pc.can_admit(8 * 16)   # full blocks
+    pc.admit(1, *_kv(20))
+    pc.admit(2, *_kv(20))
+    assert len(pc.wfree) == 3 and pc.can_admit(20)
+    pc.admit(3, *_kv(20))
+    assert not pc.can_admit(1)                  # the rings ran out
+    with pytest.raises(MemoryError):
+        pc.admit(4, *_kv(1))
+    with pytest.raises(MemoryError):
+        pc.reserve(3, 10 * 8)
+    for rid in range(4):
+        pc.release(rid)
+    assert sorted(pc.free) == list(range(40))
+    assert sorted(pc.wfree) == list(range(12))
+
+
+def test_a_ring_takes_the_next_positions_in_place():
+    """Appends past the ring reuse its blocks: the window's positions read
+    back exactly, the older ones as zeros, and no block is allocated."""
+    pc = _two_kind_pool()
+    pc.admit(0, *_kv(20))
+    pc.reserve(0, 64)
+    ring = list(pc.rings[0])
+    for t in range(20, 50):
+        k, v = _kv(1, t)
+        pc.append_token(0, k[:, :, 0], v[:, :, 0])
+    assert pc.rings[0] == ring and len(pc.wfree) == 12 - 3
+    k, _ = pc.gather(0)
+    want, _ = _kv(50)
+    np.testing.assert_array_equal(np.asarray(k[3]), np.asarray(want[3]))
+    np.testing.assert_array_equal(np.asarray(k[:3, :, 50 - WINDOW:]),
+                                  np.asarray(want[:3, :, 50 - WINDOW:]))
+    assert not np.asarray(k[:3, :, :50 - WINDOW]).any()
+
+
+def test_a_pool_with_rings_reuses_no_prefix():
+    """Windowed layers keep no prefix's KV, so a pool with rings finds no
+    prefix for a prefill to skip, though a resident prompt holds it."""
+    pc = _two_kind_pool()
+    toks = list(range(48))
+    pc.admit(0, *_kv(40), tokens=toks[:40])
+    hit = pc.lookup_prefix(toks)
+    assert hit.total_tokens == 0 and not hit.blocks
+    assert pc.stats["lookups"] == 1 and pc.stats["hits"] == 0

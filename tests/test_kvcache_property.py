@@ -10,6 +10,11 @@ Checked on every step:
 * utilization and fragmentation match the reference formulas;
 * duplicate admits / appends to absent rids raise, and a release returns
   every block.
+
+The same holds for a pool of two kinds (`test_two_kind_pool_...`): full
+layers beside windowed ones whose rings wrap, where gather reads the
+window's positions exactly and the older ones as zeros, and both kinds'
+blocks are conserved.
 """
 import numpy as np
 import pytest
@@ -152,3 +157,99 @@ def test_duplicate_admit_and_absent_rid_raise():
         pc.append_token(99, k[:, :, 0], v[:, :, 0])
     with pytest.raises(KeyError):
         pc.gather(99)
+
+
+# ---------------------------------------------------------------------------
+# two kinds: layer 1 full, layers 0 and 2 keep rings of a 6-token window
+# ---------------------------------------------------------------------------
+L2, WIN, WINDOW = 3, (0, 2), 6
+N_RING = 8                                # rings of ceil(6 / 4) + 1 = 3
+
+
+def _kv3(rid: int, start: int, n: int):
+    vals = rid * 1000 + np.arange(start, start + n, dtype=np.float32)
+    vals = vals[None] + 100.0 * np.arange(L2, dtype=np.float32)[:, None]
+    k = np.broadcast_to(vals[:, None, :, None], (L2, KV, n, HD))
+    return jnp.asarray(k), jnp.asarray(k + 0.5)
+
+
+@settings(**SET)
+@given(data=st.data())
+def test_two_kind_pool_matches_reference_model(data):
+    pc = PagedKVCache.create(L2, N_BLOCKS, KV, BLOCK, HD, dtype=jnp.float32,
+                             window_layers=WIN, window=WINDOW,
+                             n_window_blocks=N_RING)
+    ring = pc.ring
+    ref = {}
+    next_rid = 0
+    for _ in range(data.draw(st.integers(5, 40), label="n_ops")):
+        op = data.draw(st.sampled_from(OPS + ("reserve",)), label="op")
+        if op == "admit":
+            n = data.draw(st.integers(1, 3 * BLOCK + 1), label="admit_len")
+            can = pc.can_admit(n)
+            assert can == (len(pc.free) >= -(-n // BLOCK) and len(
+                pc.wfree) >= min(-(-n // BLOCK), ring)), "can_admit lied"
+            k, v = _kv3(next_rid, 0, n)
+            if not can:
+                with pytest.raises(MemoryError):
+                    pc.admit(next_rid, k, v)
+                continue
+            pc.admit(next_rid, k, v)
+            ref[next_rid] = n
+            next_rid += 1
+        elif op == "append":
+            if not ref:
+                continue
+            rid = data.draw(st.sampled_from(sorted(ref)), label="append_rid")
+            pos = ref[rid]
+            grow_full = pos // BLOCK >= len(pc.tables[rid])
+            grow_ring = (pos // BLOCK) % ring >= len(pc.rings[rid])
+            if (grow_full and not pc.free) or (grow_ring and not pc.wfree):
+                continue                 # the engine reserves: never here
+            k, v = _kv3(rid, pos, 1)
+            pc.append_token(rid, k[:, :, 0], v[:, :, 0])
+            ref[rid] = pos + 1
+        elif op == "reserve":
+            if not ref:
+                continue
+            rid = data.draw(st.sampled_from(sorted(ref)), label="reserve_rid")
+            cap = ref[rid] + data.draw(st.integers(0, 2 * BLOCK))
+            try:
+                pc.reserve(rid, cap)
+            except MemoryError:
+                pass
+        elif op == "release":
+            if not ref:
+                continue
+            rid = data.draw(st.sampled_from(sorted(ref)), label="release_rid")
+            pc.release(rid)
+            del ref[rid]
+        else:
+            if not ref:
+                continue
+            rid = data.draw(st.sampled_from(sorted(ref)), label="gather_rid")
+            n = ref[rid]
+            k, _ = pc.gather(rid)
+            want, _ = _kv3(rid, 0, n)
+            k, want = np.asarray(k), np.asarray(want)
+            lo = max(0, n - WINDOW)
+            np.testing.assert_array_equal(k[1], want[1])
+            np.testing.assert_array_equal(k[::2, :, lo:], want[::2, :, lo:])
+            assert not k[::2, :, :lo].any()
+
+        # ---- invariants, every step ----------------------------------
+        used = sum(len(b) for b in pc.tables.values())
+        rused = sum(len(r) for r in pc.rings.values())
+        assert used + len(pc.free) == N_BLOCKS, "pool leaked blocks"
+        assert rused + len(pc.wfree) == N_RING, "rings leaked blocks"
+        assert len(set(pc.wfree)) == len(pc.wfree)
+        for rid, r in pc.rings.items():
+            assert not (set(r) & set(pc.wfree)), "ring block both free+used"
+            assert min(-(-ref[rid] // BLOCK), ring) <= len(r) <= ring
+            assert len(pc.tables[rid]) * BLOCK >= ref[rid]
+        assert pc.lengths == ref
+
+    for rid in sorted(ref):
+        pc.release(rid)
+    assert sorted(pc.free) == list(range(N_BLOCKS))
+    assert sorted(pc.wfree) == list(range(N_RING))

@@ -199,7 +199,7 @@ def test_a_slot_reused_after_evict_reads_its_new_request():
 
 def test_sliding_window_through_the_xla_paged_path():
     cfg, params = _model("window")
-    assert ops.paged_decode_impl(cfg.head_dim, cfg.sliding_window) == "xla"
+    assert ops.paged_decode_impl(cfg.head_dim) == "xla"
     p = Pair(cfg, params)
     slots = [p.bind(50, 5), p.bind(51, 30)]
     toks = {s: 1 for s in slots}

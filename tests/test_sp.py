@@ -35,6 +35,25 @@ def test_multidevice_sp_equivalence():
     assert "passed" in p.stdout and "skipped" not in p.stdout
 
 
+def test_multidevice_gang_warmup():
+    """Replay tests/multidevice/test_gang_warmup.py on 4 forced host
+    devices (a 2x2 mesh, as the 4-chip benchmark cell): no gang compiles
+    after warm-up, the gang runners share one placement of the weights,
+    and the benchmark's check fails a gang without the exchange between
+    chips."""
+    import os
+    root = Path(__file__).resolve().parents[1]
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=str(root / "src"))
+    p = subprocess.run([sys.executable, "-m", "pytest", "-q", "-p",
+                        "no:cacheprovider",
+                        str(root / "tests/multidevice/test_gang_warmup.py")],
+                       capture_output=True, text=True, timeout=900, env=env)
+    assert p.returncode == 0, p.stdout[-2000:] + p.stderr[-2000:]
+    assert "3 passed" in p.stdout and "skipped" not in p.stdout
+
+
 def test_merge_partials_identity_and_empty():
     o = jnp.ones((1, 2, 3, 4))
     lse = jnp.zeros((1, 2, 3))

@@ -15,6 +15,7 @@ One CPU test at the end lowers every engine and gang program at a small
 size and checks that the weights are arguments of the program, not
 constants folded into it.
 """
+import dataclasses
 import functools
 import os
 import re
@@ -28,6 +29,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config, reduced_config
 from repro.configs.mistral_7b import PIPELINE_STAGE
+from repro.configs.trinity_mini import EP8_SHARE
 from repro.kernels import flash_attention as fa
 from repro.kernels import flash_decode as fd
 from repro.models import init_params
@@ -153,6 +155,49 @@ def test_engine_decode_updates_the_pool_in_place_on_v5e(one_chip,
     assert m.temp_size_in_bytes < pool_bytes / 100
 
 
+def test_windowed_paged_decode_compiles_for_v5e(one_chip):
+    """The paged kernel with a window over a ring of pages (Trinity-Mini's
+    windowed layers: 32 heads over 4 KV heads, a 129-page ring in a table
+    of 144)."""
+    bf = jnp.bfloat16
+    q = _spec((SLOTS, 32, 128), bf, one_chip)
+    pool = _spec((24 * 646, 4, 16, 128), bf, one_chip)
+    pages = _spec((SLOTS, 144), jnp.int32, one_chip)
+    lens = _spec((SLOTS,), jnp.int32, one_chip)
+    fn = functools.partial(fd.paged_flash_decode, ring=129, interpret=False)
+    compiled = jax.jit(fn).lower(q, pool, pool, pages, lens, lens).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_afmoe_decode_updates_both_pools_in_place_on_v5e(one_chip):
+    """Trinity-Mini at this chip's share (all 32 layers, 16 of 128 experts)
+    with the benchmark's slots: the decode program aliases the full pool
+    and the windowed layers' rings, copies neither, and reads the weights
+    where they lie (no relayout of a stacked weight)."""
+    cfg = EP8_SHARE
+    params = _shapes(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)), one_chip)
+    pools = jax.eval_shape(lambda: (lambda e: (e.kvpool.k, e.kvpool.wk))(
+        engine.ReplicaEngine(cfg, None, max_slots=SLOTS, max_len=9136)))
+    pk, wk = (_spec(p.shape, jnp.bfloat16, one_chip) for p in pools)
+    compiled = engine._decode_afmoe_program(True).lower(
+        params, pk, pk, wk, wk, _spec((SLOTS, 576), jnp.int32, one_chip),
+        _spec((SLOTS, 144), jnp.int32, one_chip),
+        _spec((3, SLOTS), jnp.int32, one_chip), cfg=cfg,
+        impl="pallas").compile()
+    m = compiled.memory_analysis()
+    pool_bytes = 2 * (pk.size + wk.size) * 2
+    assert m.alias_size_in_bytes >= pool_bytes
+    assert m.temp_size_in_bytes < 16 * 2**20
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # nor is a pool copied into its own output buffer (what a pool passed
+    # through a `cond` branch unwritten costs)
+    pools = "|".join(f"{p.shape[0] * p.shape[1]},{','.join(map(str, p.shape[2:]))}"
+                     for p in (pk, wk))
+    assert not re.search(rf"= bf16\[({pools})\]\S* copy\(", text)
+
+
 def test_gang_layer_slice_compiles_on_v5e_2x2(topo, params_shape):
     mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), gang.SEQ_AXES)
     layers = _shapes(params_shape["layers"], NamedSharding(mesh, P()))
@@ -192,7 +237,18 @@ def _small_programs():
     step = jnp.zeros((3, 2), jnp.int32)
     toks = jnp.zeros((1, 16), jnp.int32)
     mesh = gang.make_gang_mesh(1, cfg.num_heads)
-    return params, {
+    acfg = dataclasses.replace(EP8_SHARE, num_layers=4, d_model=256,
+                               num_heads=4, num_kv_heads=2, head_dim=64,
+                               d_ff=512, moe_d_ff=128, shared_d_ff=128,
+                               vocab_size=512, num_experts=8,
+                               experts_per_token=2, experts_held=4,
+                               num_dense_layers=1, sliding_window=16,
+                               layer_types=EP8_SHARE.layer_types[:4])
+    aparams = init_params(jax.random.PRNGKey(1), acfg)
+    ax = jnp.zeros((1, 16, acfg.d_model), jnp.bfloat16)
+    apool = jnp.zeros((1, 5, 2, 8, 64), jnp.bfloat16)
+    awpool = jnp.zeros((3, 7, 2, 8, 64), jnp.bfloat16)
+    return params, aparams, {
         "embed": lambda: engine._embed.lower(params, toks, cfg=cfg),
         "prefill": lambda: engine._prefill_slice.lower(
             params, x, 0, None, None, cfg=cfg, n=2),
@@ -207,24 +263,34 @@ def _small_programs():
             strategy="a2a"),
         "gang_logits": lambda: gang._last_logits.lower(params, x, cfg=cfg,
                                                        s_real=16),
+        "afmoe_prefill": lambda: engine._prefill_slice.lower(
+            aparams, ax, 0, None, None, cfg=acfg, n=2),
+        "afmoe_decode": lambda: engine._decode_afmoe_program(True).lower(
+            aparams, apool, apool, awpool, awpool, table, table, step,
+            cfg=acfg, impl="xla"),
     }
 
 
 #: program -> the weight whose shape must appear among its arguments
 USES = {"embed": "embed", "prefill": "w_gate", "suffix_prefill": "w_gate",
         "finalize": "lm_head", "decode": "w_gate", "gang_embed": "embed",
-        "gang_slice": "w_gate", "gang_logits": "lm_head"}
+        "gang_slice": "w_gate", "gang_logits": "lm_head",
+        "afmoe_prefill": "moe_w_gate", "afmoe_decode": "moe_w_gate"}
 
 
 @pytest.mark.parametrize("program", sorted(USES))
 def test_weights_are_program_arguments_not_constants(program):
-    params, programs = _small_programs()
+    params, aparams, programs = _small_programs()
     text = programs[program]().as_text()
+    if program.startswith("afmoe"):
+        params = aparams
     smallest_weight = min(a.size for a in jax.tree.leaves(params))
     assert _largest_constant(text) < smallest_weight, \
         f"{program} folds an array of a weight's size into the program"
     weight = {"embed": params["embed"], "lm_head": params["lm_head"],
-              "w_gate": params["layers"]["mlp"]["w_gate"]}[USES[program]]
+              "w_gate": params["layers"].get("mlp", {}).get("w_gate"),
+              "moe_w_gate": params.get("moe", {}).get("w_gate")
+              }[USES[program]]
     main = next(line for line in text.splitlines()
                 if "func.func public @main" in line)
     assert "tensor<" + "x".join(map(str, weight.shape)) + "x" in main, \
